@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import sys
 
 
 def main(argv=None) -> None:
@@ -24,20 +23,14 @@ def main(argv=None) -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write rows as JSON to PATH")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
 
     rows: list = []   # (name, us_per_call, derived, paper)
-    from benchmarks import paper_figs
+    from benchmarks import paper_figs, sim_speed, tpu_kernels
     paper_figs.run(rows)
-    try:
-        from benchmarks import sim_speed
-        sim_speed.run(rows)
-    except Exception as e:  # pragma: no cover
-        print(f"# sim_speed skipped: {e}", file=sys.stderr)
-    try:
-        from benchmarks import tpu_kernels
-        tpu_kernels.run(rows)
-    except Exception as e:  # pragma: no cover
-        print(f"# tpu_kernels skipped: {e}", file=sys.stderr)
+    sim_speed.run(rows)
+    tpu_kernels.run(rows)
 
     if args.json is not None:
         from benchmarks.sim_speed import _rows_as_json

@@ -427,6 +427,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write rows as JSON to PATH ('-' for stdout)")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     rows: list = []
     run(rows)
     if args.json is not None:

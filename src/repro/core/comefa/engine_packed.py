@@ -50,7 +50,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ...obs import metrics as obs_metrics
 from . import isa
+
+# Pallas kernel calls by mode: "compiled" on a TPU, "interpret" elsewhere
+_PALLAS_CALLS = obs_metrics.counter("comefa.pallas_calls")
 
 # field indices in the encoded program matrix (same layout as block._F)
 _F = {name: i for i, name in enumerate(isa.ENGINE_FIELD_NAMES)}
@@ -180,7 +184,8 @@ def datapath(a, b_read, carry, mask, x, chain: bool):
     # lane c-1 -> lane c (from_left) via word w-1's bit 31.  chain=True
     # threads corner PEs: block k's high boundary word is block k+1's
     # word 0 (bit 0 used), its low boundary block k-1's word W-1 (bit 31).
-    if chain:
+    # A single block has no neighbour (and Mosaic refuses the empty slice).
+    if chain and s.shape[-2] > 1:
         hi = jnp.concatenate(
             [s[..., 1:, :1], jnp.zeros_like(s[..., :1, :1])], axis=-2)
         lo = jnp.concatenate(
@@ -280,34 +285,36 @@ class PallasEngine(PackedXlaEngine):
 
     Same packed layout (so `to_device`/`to_host` are inherited); the scan
     runs inside one `pl.pallas_call` over the slot grid
-    (`repro.kernels.comefa_step`), interpret-mode on non-TPU backends.
-    Sharded grid dispatches fall back to the XLA scan
-    (`sharded_fallback`): a pallas_call does not partition across a mesh.
+    (`repro.kernels.comefa_step`): compiled by Mosaic on a TPU, in the
+    Pallas interpreter on every other backend.  Each call counts under
+    ``comefa.pallas_calls{mode=compiled|interpret}``.  Sharded grid
+    dispatches fall back to the XLA scan (`sharded_fallback`): a
+    pallas_call does not partition across a mesh.
     """
 
     name = "pallas"
 
     def __init__(self):
         self.sharded_fallback = PackedXlaEngine()
+        self.interpret = jax.default_backend() != "tpu"
 
-    @staticmethod
-    def _kernel():
+    def _run(self, mem, carry, mask, prog, chain: bool, per_slot: bool):
         from ...kernels import comefa_step    # deferred: optional dep gate
-        return comefa_step
+        _PALLAS_CALLS.inc(mode="interpret" if self.interpret else "compiled")
+        return comefa_step.run_packed(mem, carry, mask, prog, chain=chain,
+                                      per_slot=per_slot,
+                                      interpret=self.interpret)
 
     def run(self, state, prog, chain: bool):
         mem, carry, mask = state
-        ks = self._kernel()
         if mem.ndim == 3:      # single array: add the slot axis the grid has
-            out = ks.run_packed(mem[None], carry[None], mask[None], prog,
-                                chain=chain, per_slot=False)
+            out = self._run(mem[None], carry[None], mask[None], prog,
+                            chain, per_slot=False)
             return tuple(x[0] for x in out)
-        return ks.run_packed(mem, carry, mask, prog, chain=chain,
-                             per_slot=False)
+        return self._run(mem, carry, mask, prog, chain, per_slot=False)
 
     def run_per_slot(self, state, progs, chain: bool):
-        return self._kernel().run_packed(*state, progs, chain=chain,
-                                         per_slot=True)
+        return self._run(*state, progs, chain, per_slot=True)
 
 
 def pallas_available() -> bool:
@@ -315,7 +322,7 @@ def pallas_available() -> bool:
     try:
         from ...kernels import comefa_step  # noqa: F401
         return True
-    except Exception:       # pragma: no cover - environment-dependent
+    except ImportError:     # pragma: no cover - environment-dependent
         return False
 
 
@@ -326,24 +333,22 @@ _PALLAS = None
 def get_engine(name: str):
     """Packed-engine registry half of `block.get_engine`.
 
-    ``"packed"`` auto-selects: the Pallas kernel where it runs compiled
-    (TPU), the pure-XLA packed scan elsewhere (Pallas interpret mode
-    emulates - correct but not faster - so CPU/GPU default to XLA).
-    ``"packed-xla"`` and ``"pallas"`` force one side.
+    ``"packed"`` auto-selects: the compiled Pallas kernel on a TPU (or an
+    error, never a quiet swap to the XLA scan), the pure-XLA packed scan
+    elsewhere (Pallas interpret mode emulates - correct but not faster -
+    so CPU/GPU default to XLA).  ``"packed-xla"`` and ``"pallas"`` force
+    one side.
     """
     global _PALLAS
     if name == "packed":
-        if jax.default_backend() == "tpu" and pallas_available():
-            name = "pallas"
-        else:
-            return _PACKED
+        name = "pallas" if jax.default_backend() == "tpu" else "packed-xla"
     if name == "packed-xla":
         return _PACKED
     if name == "pallas":
         if not pallas_available():
             raise RuntimeError(
                 "engine 'pallas' requested but jax.experimental.pallas "
-                "is unavailable; use engine='packed' for the XLA fallback")
+                "is unavailable; use engine='packed-xla'")
         if _PALLAS is None:
             _PALLAS = PallasEngine()
         return _PALLAS

@@ -36,7 +36,8 @@ def _unpack_block(packed: jax.Array, bk: int, dtype) -> jax.Array:
     """[bk/32, bn] uint32 planes -> [bk, bn] {0,1} matrix of `dtype`."""
     rep = jnp.repeat(packed, LANES, axis=0)                    # [bk, bn]
     sh = jax.lax.broadcasted_iota(jnp.uint32, (bk, 1), 0) % LANES
-    return ((rep >> sh) & 1).astype(dtype)
+    # Mosaic has no uint32 -> float cast; the {0,1} bits go through int32
+    return ((rep >> sh) & 1).astype(jnp.int32).astype(dtype)
 
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, bits: int,

@@ -16,9 +16,10 @@ Two program layouts serve the two grid dispatch modes:
   * ``per_slot=True``: a stacked ``[S, T, F]`` program, slot s scans its
     own stream (`ComefaGrid.run_per_slot`'s per-slice FSM).
 
-On non-TPU backends the call runs in interpret mode, like the other
-Pallas kernels in this package - bit-identical, if not faster, than the
-pure-XLA packed scan it mirrors (`tests/test_engines.py` pins both).
+`engine_packed.PallasEngine` runs it Mosaic-compiled on a TPU and in
+interpret mode elsewhere - bit-identical, if not faster, than the
+pure-XLA packed scan it mirrors (`tests/test_engines.py` pins both;
+`tests/test_tpu_compile.py` compiles it for a described v5e).
 """
 from __future__ import annotations
 
@@ -43,15 +44,13 @@ def _step_kernel(prog_ref, mem_in, carry_in, mask_in,
         carry, mask = latches                       # [nb, W] loop registers
         # this cycle's encoded fields ([F] vector), then the shared
         # word-mask bundle; the selects stay on-chip scalars, cheap/step
-        fields = pl.load(prog_ref,
-                         (pl.ds(0, 1), pl.ds(t, 1), slice(None)))[0, 0]
+        fields = prog_ref[pl.ds(0, 1), pl.ds(t, 1), :][0, 0]
         x = prepare_fields(lambda name: fields[_F[name]])
 
         def row(i):
             # slot axis and row axis as width-1 dynamic slices: interpret
             # mode's discharge rejects bare int indices mixed with pl.ds
-            return pl.load(mem_out, (pl.ds(0, 1), slice(None),
-                                     pl.ds(i, 1), slice(None)))[0, :, 0, :]
+            return mem_out[pl.ds(0, 1), :, pl.ds(i, 1), :][0, :, 0, :]
 
         a = row(x["src1"])
         b_read = row(x["src2"])
@@ -60,9 +59,9 @@ def _step_kernel(prog_ref, mem_in, carry_in, mask_in,
 
         def write(i, val, we):
             idx = (pl.ds(0, 1), slice(None), pl.ds(i, 1), slice(None))
-            old = pl.load(mem_out, idx)[0, :, 0, :]
+            old = mem_out[idx][0, :, 0, :]
             merged = (old & ~we) | (val & we)
-            pl.store(mem_out, idx, merged[None, :, None, :])
+            mem_out[idx] = merged[None, :, None, :]
 
         # port 1 retires before port 2 reads (same order as the scans)
         write(x["dst"], val1, we1)
@@ -78,15 +77,14 @@ def _step_kernel(prog_ref, mem_in, carry_in, mask_in,
 @functools.partial(jax.jit,
                    static_argnames=("chain", "per_slot", "interpret"))
 def run_packed(mem, carry, mask, prog, *, chain: bool, per_slot: bool,
-               interpret: bool = None):
+               interpret: bool):
     """Execute a packed program matrix with the Pallas step kernel.
 
     mem ``[S, nb, 128, W]`` uint32, carry/mask ``[S, nb, W]`` uint32;
     prog int32 ``[T, F]`` (shared) or ``[S, T, F]`` (``per_slot=True``).
-    Returns the updated ``(mem, carry, mask)``.
+    Returns the updated ``(mem, carry, mask)``.  ``interpret`` runs the
+    Pallas interpreter (any backend) instead of the Mosaic-compiled kernel.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     s, nb, n_rows, w = mem.shape
     assert w == N_WORDS, mem.shape
     prog3 = prog if per_slot else prog[None]

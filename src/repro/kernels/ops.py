@@ -34,17 +34,25 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
 
 
 def bitplane_matmul(x, w_packed, scale, *, bits, block_m=128, block_n=128,
-                    block_k=128, interpret=None, out_dtype=jnp.float32):
-    """Padded/dispatched `kernels.bitplane_matmul` (docs there)."""
+                    block_k=256, interpret=None, out_dtype=jnp.float32):
+    """Padded/dispatched `kernels.bitplane_matmul` (docs there).
+
+    A K or N block that does not divide its dimension becomes the whole
+    dimension, which Mosaic always accepts (e.g. K=960 in one block).
+    The default ``block_k=256`` keeps the packed weight block at K/32 = 8
+    sublanes, the uint32 tile height on a TPU.
+    """
     if interpret is None:
         interpret = _interpret_default()
     m, k = x.shape
     n = w_packed.shape[2]
     bm = min(block_m, max(8, m))
+    bk = block_k if k % block_k == 0 else k
+    bn = block_n if n % block_n == 0 else n
     xp = _pad_to(x, 0, bm)
     yp = _bpm.bitplane_matmul(
-        xp, w_packed, scale, bits=bits, bm=bm, bn=block_n,
-        bk=block_k, interpret=interpret, out_dtype=out_dtype)
+        xp, w_packed, scale, bits=bits, bm=bm, bn=bn,
+        bk=bk, interpret=interpret, out_dtype=out_dtype)
     return yp[:m]
 
 

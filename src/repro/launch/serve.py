@@ -24,10 +24,11 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    from repro import configs
+    from repro import compile_cache, configs
     from repro.models import common, lm
     from repro.serve import engine
 
+    compile_cache.enable()
     cfg = configs.get(args.arch, quant_bits=args.quant)
     if args.reduced:
         cfg = common.reduced(cfg, vocab=512, d_model=128, d_ff=256,

@@ -17,7 +17,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(n_stages: int, n_micro: int) -> float:
@@ -71,7 +70,7 @@ def pipelined_apply(fn: Callable, mesh: Mesh, axis: str = "stage"):
         outs = jax.lax.psum(outs, axis)
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(axis), P()), out_specs=P(),
-        check_rep=False)
+        check_vma=False)

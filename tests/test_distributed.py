@@ -132,7 +132,6 @@ def test_compressed_pod_allreduce_multidevice():
     out = run_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.parallel import compression
 
         mesh = jax.make_mesh((8,), ("pod",))
@@ -140,10 +139,10 @@ def test_compressed_pod_allreduce_multidevice():
         g = jnp.asarray(rng.normal(size=(8, 4096)), jnp.float32)
         err = jnp.zeros_like(g)
 
-        f = shard_map(lambda gg, ee: compression.compress_psum(
-                          gg[0], ee[0], "pod"),
-                      mesh=mesh, in_specs=(P("pod"), P("pod")),
-                      out_specs=(P(), P("pod")), check_rep=False)
+        f = jax.shard_map(lambda gg, ee: compression.compress_psum(
+                              gg[0], ee[0], "pod"),
+                          mesh=mesh, in_specs=(P("pod"), P("pod")),
+                          out_specs=(P(), P("pod")), check_vma=False)
         avg, _ = jax.jit(f)(g, err)
         expect = np.asarray(g).mean(0)
         rel = np.linalg.norm(np.asarray(avg) - expect) / \

@@ -131,7 +131,6 @@ def test_chunked_update_matches_unchunked():
 def test_compression_error_feedback_converges():
     """EF-int8 mean over an axis: residual shrinks the bias to ~0."""
     mesh = jax.make_mesh((1,), ("pod",))
-    from jax.experimental.shard_map import shard_map
 
     g = jnp.asarray(np.random.default_rng(0).normal(size=(4096,)),
                     jnp.float32)
@@ -139,10 +138,10 @@ def test_compression_error_feedback_converges():
 
     @jax.jit
     def step(g, err):
-        f = shard_map(
+        f = jax.shard_map(
             lambda gg, ee: compression.compress_psum(gg, ee, "pod"),
             mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False)
+            check_vma=False)
         return f(g, err)
 
     avg, err1 = step(g, err)

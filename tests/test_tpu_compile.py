@@ -1,0 +1,76 @@
+"""Ahead-of-time compiles of the main-path kernels for a described TPU v5e.
+
+Nothing runs: each case lowers and compiles a kernel at its real size for
+a v5e chip that is described, not attached, so Mosaic refuses here what
+it would refuse on the chip (unaligned blocks, unsupported casts, too much
+VMEM).  The topology is described inside a fixture, never at import: only
+the test worker given this file loads the TPU compiler.  The persistent
+compilation cache is off around the compiles - an entry compiled for a
+described chip cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.comefa import isa
+from repro.core.comefa.engine_packed import N_WORDS
+from repro.kernels import comefa_step, ops
+
+G = 8
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("chain", [False, True])
+@pytest.mark.parametrize("per_slot", [False, True])
+@pytest.mark.parametrize("nb", [1, 8, 16])
+def test_comefa_step_compiles_for_v5e(one_chip, nb, per_slot, chain):
+    """The Pallas step kernel: G=8 slots x nb blocks (nb=16 is the block
+    count of a 2560-wide projection, nb=1 a default `ComefaArray`),
+    shared and per-slot programs."""
+    t = 288                                  # a mul16 program, 32-padded
+    f = isa.N_ENGINE_FIELDS
+    prog = (G, t, f) if per_slot else (t, f)
+    args = [_spec((G, nb, isa.N_ROWS, N_WORDS), jnp.uint32, one_chip),
+            _spec((G, nb, N_WORDS), jnp.uint32, one_chip),
+            _spec((G, nb, N_WORDS), jnp.uint32, one_chip),
+            _spec(prog, jnp.int32, one_chip)]
+    compiled = jax.jit(lambda m, c, k, p: comefa_step.run_packed(
+        m, c, k, p, chain=chain, per_slot=per_slot, interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bitplane_matmul_compiles_for_v5e(one_chip):
+    """The MXU bit-plane kernel at a smollm-360m FFN projection, w4:
+    K=960 is not a multiple of the K block, so it runs as one block."""
+    k, n, bits = 960, 2560, 4
+    args = [_spec((8, k), jnp.float32, one_chip),
+            _spec((bits, k // 32, n), jnp.uint32, one_chip),
+            _spec((1, n), jnp.float32, one_chip)]
+    compiled = jax.jit(lambda x, w, s: ops.bitplane_matmul(
+        x, w, s, bits=bits, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
