@@ -70,12 +70,21 @@ _F = {name: i for i, name in enumerate(isa.ENGINE_FIELD_NAMES)}
 #   comefa.host_syncs / comefa.device_puts {kind=array|grid}
 #   comefa.dispatches / comefa.dispatch_cycles {kind=..., engine=...}
 #   comefa.engine_select{engine=...}
+#   comefa.transfer_bytes{kind=array|grid, dir=h2d|d2h, what=state|program}
 _ENCODE_EVENTS = obs_metrics.counter("comefa.encode_cache")
 _HOST_SYNCS = obs_metrics.counter("comefa.host_syncs")
 _DEVICE_PUTS = obs_metrics.counter("comefa.device_puts")
 _DISPATCHES = obs_metrics.counter("comefa.dispatches")
 _DISPATCH_CYCLES = obs_metrics.counter("comefa.dispatch_cycles")
 _ENGINE_SELECT = obs_metrics.counter("comefa.engine_select")
+_TRANSFER_BYTES = obs_metrics.counter("comefa.transfer_bytes")
+
+
+def count_transfer(arrays, kind: str, direction: str, what: str) -> None:
+    """Count the bytes of `arrays` as one host<->device crossing
+    (``direction`` is ``"h2d"`` or ``"d2h"``)."""
+    _TRANSFER_BYTES.inc(sum(int(a.nbytes) for a in arrays), kind=kind,
+                        dir=direction, what=what)
 
 
 def _prog_label(program) -> str:
@@ -437,7 +446,7 @@ _DEVICE_MAT_CACHE: dict = {}
 _DEVICE_MAT_CACHE_MAX = 512
 
 
-def device_mat(mat: np.ndarray):
+def device_mat(mat: np.ndarray, kind: str = "array"):
     """Device-side copy of an encoded program matrix, cached when safe.
 
     Only *frozen* matrices cache - exactly the encode-cache residents
@@ -447,15 +456,18 @@ def device_mat(mat: np.ndarray):
     (temporary `_concat_encoded` / `run_per_slot` stacks take this path).
     Entries key on ``id(mat)`` and hold a strong reference to the host
     matrix, so an id can never be recycled out from under its entry;
-    FIFO eviction bounds both caches the same way.
+    FIFO eviction bounds both caches the same way.  Every upload counts
+    its bytes under ``comefa.transfer_bytes{kind=kind, what=program}``.
     """
     if mat.flags.writeable:
+        count_transfer((mat,), kind, "h2d", "program")
         return jnp.asarray(mat)
     entry = _DEVICE_MAT_CACHE.get(id(mat))
     if entry is not None:
         _ENCODE_EVENTS.inc(event="device_hits")
         return entry[1]
     _ENCODE_EVENTS.inc(event="device_misses")
+    count_transfer((mat,), kind, "h2d", "program")
     dev = jnp.asarray(mat)
     if len(_DEVICE_MAT_CACHE) >= _DEVICE_MAT_CACHE_MAX:
         _DEVICE_MAT_CACHE.pop(next(iter(_DEVICE_MAT_CACHE)))
@@ -510,6 +522,7 @@ class ComefaArray:
             with obs_trace.span("array.host_sync", engine=self.engine.name):
                 self._mem, self._carry, self._mask = self.engine.to_host(
                     self._dev)
+            count_transfer(self._dev, "array", "d2h", "state")
             self._dev = None
             self.host_syncs += 1
             _HOST_SYNCS.inc(kind="array")
@@ -620,6 +633,7 @@ class ComefaArray:
                                              self._mask)
                 self.device_puts += 1
                 _DEVICE_PUTS.inc(kind="array")
+                count_transfer(self._dev, "array", "h2d", "state")
             self._dev = engine.run(self._dev, device_mat(mat), self.chain)
         self.cycles += int(mat.shape[0])
         _DISPATCHES.inc(kind="array", engine=engine.name)

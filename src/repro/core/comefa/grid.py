@@ -153,8 +153,14 @@ class ComefaGrid:
             engine = self._active_engine()
             with obs_trace.span("grid.host_sync", engine=engine.name,
                                 slots=self.g):
+                if obs_trace.enabled():
+                    # the wait for the device, apart from the copy and
+                    # unpack that follow (to_host would wait anyway)
+                    with obs_trace.span("grid.wait"):
+                        jax.block_until_ready(self._dev)
                 self._mem, self._carry, self._mask = engine.to_host(
                     self._dev)
+            block.count_transfer(self._dev, "grid", "d2h", "state")
             self._dev = None
             self.host_syncs += 1
             block._HOST_SYNCS.inc(kind="grid")
@@ -288,19 +294,20 @@ class ComefaGrid:
         """
         assert len(programs) == self.g, (len(programs), self.g)
         with obs_trace.span("grid.run_per_slot", slots=self.g) as sp:
-            mats = [encoded(p) for p in programs]
-            counts = [int(m.shape[0]) for m in mats]
-            longest = max(counts, default=0)
-            if longest == 0:
-                return counts
-            # bucketed padding bounds the number of distinct scan lengths a
-            # sweep of value-dependent programs can trigger (each length is
-            # one jit trace)
-            t_pad = -(-longest // _SLOT_PAD_QUANTUM) * _SLOT_PAD_QUANTUM
-            stack = np.zeros((self.g, t_pad, isa.N_ENGINE_FIELDS),
-                             dtype=np.int32)   # zero fields == idle cycle
-            for g, m in enumerate(mats):
-                stack[g, :m.shape[0]] = m
+            with obs_trace.span("grid.stack"):
+                mats = [encoded(p) for p in programs]
+                counts = [int(m.shape[0]) for m in mats]
+                longest = max(counts, default=0)
+                if longest == 0:
+                    return counts
+                # bucketed padding bounds the number of distinct scan
+                # lengths a sweep of value-dependent programs can trigger
+                # (each length is one jit trace)
+                t_pad = -(-longest // _SLOT_PAD_QUANTUM) * _SLOT_PAD_QUANTUM
+                stack = np.zeros((self.g, t_pad, isa.N_ENGINE_FIELDS),
+                                 dtype=np.int32)   # zero fields == idle
+                for g, m in enumerate(mats):
+                    stack[g, :m.shape[0]] = m
             engine = self._active_engine()
             # makespan = the longest real program: slices run concurrently,
             # the slowest bounds the dispatch
@@ -330,18 +337,20 @@ class ComefaGrid:
     def _ensure_device(self, engine) -> None:
         if self._dev is not None:
             return
-        dev = engine.to_device(self._mem, self._carry, self._mask)
-        if self._shardings is not None:
-            # packed state keeps the grid axis leading and the same rank
-            # (row axis at -2, lanes packed in place), so the reference
-            # specs transfer unchanged
-            s_mem, s_latch, _ = self._shardings
-            dev = (jax.device_put(dev[0], s_mem),
-                   jax.device_put(dev[1], s_latch),
-                   jax.device_put(dev[2], s_latch))
+        with obs_trace.span("grid.upload"):
+            dev = engine.to_device(self._mem, self._carry, self._mask)
+            if self._shardings is not None:
+                # packed state keeps the grid axis leading and the same
+                # rank (row axis at -2, lanes packed in place), so the
+                # reference specs transfer unchanged
+                s_mem, s_latch, _ = self._shardings
+                dev = (jax.device_put(dev[0], s_mem),
+                       jax.device_put(dev[1], s_latch),
+                       jax.device_put(dev[2], s_latch))
         self._dev = dev
         self.device_puts += 1
         block._DEVICE_PUTS.inc(kind="grid")
+        block.count_transfer(dev, "grid", "h2d", "state")
 
     def _device_prog(self, prog: np.ndarray):
         """Program matrix as a device array (sharded when a mesh is set).
@@ -352,9 +361,11 @@ class ComefaGrid:
         keyed device-mat cache (frozen encode-cache matrices skip the
         upload entirely).
         """
-        if self._shardings is not None:
-            return jax.device_put(jnp.asarray(prog), self._shardings[2])
-        return block.device_mat(prog)
+        with obs_trace.span("grid.program_upload"):
+            if self._shardings is not None:
+                block.count_transfer((prog,), "grid", "h2d", "program")
+                return jax.device_put(jnp.asarray(prog), self._shardings[2])
+            return block.device_mat(prog, kind="grid")
 
     def _dispatch(self, mat: np.ndarray) -> int:
         if mat.shape[0] == 0:

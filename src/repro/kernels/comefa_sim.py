@@ -462,6 +462,18 @@ def _broadcast_quote(k: int, n: int, w_bits: int, x_bits: int,
     return _BCAST_QUOTES[key]
 
 
+def _extract_batched(grid: ComefaGrid, base: int, acc_bits: int,
+                     n: int) -> np.ndarray:
+    """Every slot's first `n` accumulator lanes, ``[G, n]``, in a
+    ``kernel.extract`` span (after ``kernel.gemv_batched`` has closed)."""
+    out = np.empty((grid.g, n), dtype=np.int64)
+    with obs_trace.span("kernel.extract"):
+        for g in range(grid.g):
+            vals = layout.extract(grid.slot(g), base, acc_bits)
+            out[g] = vals.reshape(-1)[:n]
+    return out
+
+
 def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
                         x_bits: int, acc_bits: int = 32,
                         optimized: bool = True, mesh=None,
@@ -545,16 +557,17 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
                         mode="broadcast") as sp:
         for tile in plan.tiles():
             buf = plan.buffers[tile.buffer]
-            for g in range(G):
-                slot = grid.slot(g)
-                for j_local, j in enumerate(range(tile.k_start,
-                                                  tile.k_end)):
-                    wj = np.pad(w[g, j], (0, pad)).reshape(nb, lanes)
-                    rows = buf.weight_rows(j_local, w_bits)
-                    layout.place(slot, wj, rows.base, w_bits)
-                    assert 0 <= int(x[g, j]) < (1 << x_bits)
-                    layout.place(slot, np.full(lanes, int(x[g, j])),
-                                 x_rows[j_local].base, x_bits)
+            with obs_trace.span("kernel.place"):
+                for g in range(G):
+                    slot = grid.slot(g)
+                    for j_local, j in enumerate(range(tile.k_start,
+                                                      tile.k_end)):
+                        wj = np.pad(w[g, j], (0, pad)).reshape(nb, lanes)
+                        rows = buf.weight_rows(j_local, w_bits)
+                        layout.place(slot, wj, rows.base, w_bits)
+                        assert 0 <= int(x[g, j]) < (1 << x_bits)
+                        layout.place(slot, np.full(lanes, int(x[g, j])),
+                                     x_rows[j_local].base, x_bits)
             prog = _gemv_batched_chunk_program(plan, tile, x_rows,
                                                optimized=optimized)
             grid.run(prog)
@@ -572,11 +585,7 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     if stats is not None:
         stats["cycles"] = grid.cycles
         stats["mode"] = "broadcast"
-    out = np.empty((G, n), dtype=np.int64)
-    for g in range(G):
-        vals = layout.extract(grid.slot(g), plan.acc.base, acc_bits)
-        out[g] = vals.reshape(-1)[:n]
-    return out
+    return _extract_batched(grid, plan.acc.base, acc_bits, n)
 
 
 def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
@@ -606,13 +615,14 @@ def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
                         mode="per_slot", recode=recode) as sp:
         for tile in plan.tiles():
             buf = plan.buffers[tile.buffer]
-            for g in range(G):
-                slot = grid.slot(g)
-                for j_local, j in enumerate(range(tile.k_start,
-                                                  tile.k_end)):
-                    wj = np.pad(w[g, j], (0, pad)).reshape(nb, lanes)
-                    rows = buf.weight_rows(j_local, w_bits)
-                    layout.place(slot, wj, rows.base, w_bits)
+            with obs_trace.span("kernel.place"):
+                for g in range(G):
+                    slot = grid.slot(g)
+                    for j_local, j in enumerate(range(tile.k_start,
+                                                      tile.k_end)):
+                        wj = np.pad(w[g, j], (0, pad)).reshape(nb, lanes)
+                        rows = buf.weight_rows(j_local, w_bits)
+                        layout.place(slot, wj, rows.base, w_bits)
             progs = [
                 plan.tile_program(
                     tile, x[g, tile.k_start:tile.k_end],
@@ -638,8 +648,4 @@ def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     if stats is not None:
         stats["cycles"] = grid.cycles
         stats["mode"] = "per_slot"
-    out = np.empty((G, n), dtype=np.int64)
-    for g in range(G):
-        vals = layout.extract(grid.slot(g), plan.acc.base, acc_bits)
-        out[g] = vals.reshape(-1)[:n]
-    return out
+    return _extract_batched(grid, plan.acc.base, acc_bits, n)

@@ -16,9 +16,15 @@ CoMeFa (a wall-clock simulator of a cycle-priced machine):
     wall-clock track in Perfetto.
 
 Tracing is OFF by default and must stay near-free when off: `span()`
-returns a shared no-op context manager without touching the ring buffer
-or the clock (the benchmark suite asserts the disabled overhead on the
-hot grid rows stays under 2%).  Arm it with the environment variable::
+returns a shared no-op context manager without touching the ring buffer,
+the clock or JAX (the benchmark suite asserts the disabled overhead on
+the hot grid rows stays under 2%).  When on, every wall-clock span also
+enters a `jax.profiler.TraceAnnotation` of its own name (no attributes),
+so a profiler trace taken over the same run shows the program's spans
+on the device trace's clock; JAX is imported on the first enabled span,
+never at import.  Program span names never start with ``bench.``: that
+prefix is the benchmark harness's own.  Arm it with the environment
+variable::
 
     REPRO_COMEFA_TRACE=trace.json python ...
 
@@ -87,10 +93,28 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """A live wall-clock span; records into the tracer on exit."""
+# jax.profiler.TraceAnnotation, bound on the first enabled span
+_annotation = None
 
-    __slots__ = ("_tracer", "name", "attrs", "_start")
+
+def _open_annotation(name: str):
+    """Enter a profiler annotation named `name` and return it."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    ann = _annotation(name)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    """A live wall-clock span; records into the tracer on exit.
+
+    It runs inside a profiler annotation of the same name, entered
+    before its clock starts and exited after its clock stops."""
+
+    __slots__ = ("_tracer", "name", "attrs", "_start", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict):
         self._tracer = tracer
@@ -98,6 +122,7 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        self._annotation = _open_annotation(self.name)
         self._start = time.perf_counter()
         return self
 
@@ -109,10 +134,13 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         # record even when unwinding: the span closed, nesting holds,
         # and the event carries the exception type for the timeline
-        if exc_type is not None:
-            self.attrs["error"] = exc_type.__name__
-        self._tracer._record(self.name, self._start, time.perf_counter(),
-                             self.attrs)
+        end = time.perf_counter()
+        try:
+            if exc_type is not None:
+                self.attrs["error"] = exc_type.__name__
+            self._tracer._record(self.name, self._start, end, self.attrs)
+        finally:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 

@@ -189,7 +189,6 @@ def serve_continuous(params, requests: List[Request], cfg: Config, *,
     outputs: List[Optional[List[int]]] = [None] * len(requests)
     slot_req = [None] * slots        # request id per row, None = idle
     slot_pos = [0] * slots           # prompt tokens consumed per row
-    slot_span = [None] * slots       # open serve.request span per row
     tok = np.zeros((slots, 1), np.int32)
     index = np.zeros((slots,), np.int32)
     step = slot_steps = 0
@@ -206,11 +205,6 @@ def serve_continuous(params, requests: List[Request], cfg: Config, *,
                 slot_req[g], slot_pos[g], index[g] = rid, 0, 0
                 outputs[rid] = []
                 tok[g, 0] = int(req.prompt[0])
-                sp = obs_trace.span("serve.request", request=rid, slot=g,
-                                    prompt=len(req.prompt),
-                                    steps=req.steps)
-                slot_span[g] = sp
-                sp.__enter__()
             _QUEUE_DEPTH.set(len(queue))
             live = np.array([r is not None for r in slot_req])
             if executor is not None:
@@ -241,16 +235,11 @@ def serve_continuous(params, requests: List[Request], cfg: Config, *,
                 tok[g, 0] = t
                 if len(emitted) >= req.steps:
                     slot_req[g] = None
-                    slot_span[g].__exit__(None, None, None)
-                    slot_span[g] = None
                     _REQUESTS_DONE.inc()
     finally:
         if executor is not None:
             executor.active_mask = None
             cm.set_linear_hook(prev_hook)
-        for sp in slot_span:
-            if sp is not None:
-                sp.__exit__(None, None, None)
     if stats is not None:
         stats["steps"] = step
         stats["slot_steps"] = slot_steps

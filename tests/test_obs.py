@@ -16,10 +16,22 @@ CoMeFa stack:
     ``ph``/``ts``/``dur`` fields on both the wall-clock and
     modeled-cycles processes;
   * the ``REPRO_COMEFA_TRACE`` smoke path: a traced per-slot GEMV sweep
-    must produce a non-empty trace with both time domains present.
+    must produce a non-empty trace with both time domains present;
+  * the profiler clock: enabled spans appear as host events of a
+    `jax.profiler` trace (closing on exceptions too), disabled spans build
+    no annotation and import no JAX;
+  * where the grid's host buckets nest (upload, program upload, stack,
+    wait, place, extract) in both batched-GEMV modes, and the exact
+    ``comefa.transfer_bytes`` of a small known array and grid.
 """
+import glob
 import json
+import os
+import re
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -423,3 +435,221 @@ def test_generate_prime_emits_per_token_spans():
     # children close before the parent: every prime_token precedes prime
     assert max(i for i, n in enumerate(names)
                if n == "serve.prime_token") < names.index("serve.prime")
+
+
+# ---------------------------------------------------------------------------
+# the profiler clock: obs spans as jax.profiler annotations
+# ---------------------------------------------------------------------------
+
+def _host_events(directory):
+    """(name, start_ns, end_ns) of every host event of a profiler trace."""
+    import jax
+    path, = glob.glob(f"{directory}/**/*.xplane.pb", recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_enabled_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    trace.configure(enabled=True)
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace.span("obs.outer", a=1):
+            with trace.span("obs.inner"):
+                pass
+            with pytest.raises(ValueError):
+                with trace.span("obs.raised"):
+                    raise ValueError("boom")
+    finally:
+        jax.profiler.stop_trace()
+    events = {name: (s, e) for name, s, e in _host_events(tmp_path)
+              if name.startswith("obs.")}
+    assert set(events) == {"obs.outer", "obs.inner", "obs.raised"}
+    (o0, o1) = events["obs.outer"]
+    for child in ("obs.inner", "obs.raised"):
+        c0, c1 = events[child]
+        assert o0 <= c0 <= c1 <= o1
+    assert [e.name for e in trace.get_tracer().events()] == \
+        ["obs.inner", "obs.raised", "obs.outer"]
+
+
+class _FakeAnnotation:
+    made = []
+
+    def __init__(self, name):
+        self.name = name
+        self.open = False
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.open = True
+        return self
+
+    def __exit__(self, *exc):
+        self.open = False
+        return False
+
+
+def test_disabled_span_builds_no_annotation(monkeypatch):
+    monkeypatch.setattr(trace, "_annotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "made", [])
+    assert trace.span("off") is trace.NULL_SPAN
+    with trace.span("off"):
+        pass
+    assert _FakeAnnotation.made == []
+
+    trace.configure(enabled=True)
+    with pytest.raises(KeyError):
+        with trace.span("on", x=1):
+            assert [a.open for a in _FakeAnnotation.made] == [True]
+            raise KeyError("closed on unwinding")
+    ann, = _FakeAnnotation.made
+    assert ann.name == "on" and not ann.open
+
+
+def test_disabled_span_imports_no_jax():
+    env = {k: v for k, v in os.environ.items() if k != trace.ENV_VAR}
+    src = Path(__file__).resolve().parents[1] / "src"
+    env["PYTHONPATH"] = str(src)
+    code = ("import sys\n"
+            "from repro.obs import trace\n"
+            "assert trace.span('x') is trace.NULL_SPAN\n"
+            "with trace.span('x'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=60)
+
+
+def test_no_program_span_is_named_like_the_harness():
+    """``bench.*`` names the benchmark harness's own annotations."""
+    src = Path(__file__).resolve().parents[1] / "src"
+    pat = re.compile(r"span\(\s*f?[\"']bench\.")
+    hits = [str(p) for p in src.rglob("*.py") if pat.search(p.read_text())]
+    assert hits == []
+
+
+# ---------------------------------------------------------------------------
+# the grid's host buckets: where each span nests
+# ---------------------------------------------------------------------------
+
+def _inside(child, parent) -> bool:
+    return (child.tid == parent.tid and parent.ts <= child.ts
+            and child.ts + child.dur <= parent.ts + parent.dur)
+
+
+def _parents(ev, events, name):
+    return [p for p in events if p.name == name and _inside(ev, p)]
+
+
+@pytest.mark.parametrize("recode", [None, "naive"])
+def test_grid_host_spans_nest(recode):
+    from repro.kernels import comefa_sim
+
+    trace.configure(enabled=True)
+    rng = np.random.default_rng(3)
+    g, k, n, wb, xb = 2, 48, 200, 3, 4
+    w = rng.integers(0, 1 << wb, size=(g, k, n))
+    x = rng.integers(0, 1 << xb, size=(g, k))
+    y = comefa_sim.comefa_gemv_batched(w, x, w_bits=wb, x_bits=xb,
+                                       acc_bits=16, recode=recode,
+                                       engine="packed")
+    assert np.array_equal(y, np.einsum("gkn,gk->gn", w, x))
+    evs = [e for e in trace.get_tracer().events()
+           if e.track == trace.WALL_TRACK]
+    named = {}
+    for e in evs:
+        named.setdefault(e.name, []).append(e)
+    kernel, = named["kernel.gemv_batched"]
+    dispatch = "grid.run_per_slot" if recode else "grid.dispatch"
+    n_dispatch = len(named[dispatch])
+    assert n_dispatch >= 2            # a later tile's placement syncs
+    # one placement span per tile, inside the kernel's span
+    assert len(named["kernel.place"]) == n_dispatch
+    assert all(_inside(e, kernel) for e in named["kernel.place"])
+    # the final extract closes after the kernel's span
+    extract, = named["kernel.extract"]
+    assert extract.ts >= kernel.ts + kernel.dur
+    # the first dispatch uploads the fresh state, each later one the state
+    # its tile's placement synced back
+    assert len(named["grid.upload"]) == n_dispatch
+    for name in ("grid.upload", "grid.program_upload"):
+        assert all(len(_parents(e, evs, dispatch)) == 1
+                   for e in named[name])
+    assert len(named["grid.program_upload"]) == n_dispatch
+    if recode:
+        assert len(named["grid.stack"]) == n_dispatch
+        assert all(_parents(e, evs, dispatch) for e in named["grid.stack"])
+    else:
+        assert "grid.stack" not in named
+    # a host sync in every tile's placement but the first, and one in the
+    # extract; the wait for the device sits inside each
+    syncs = named["grid.host_sync"]
+    assert len(syncs) == n_dispatch
+    assert sum(1 for s in syncs if _parents(s, evs, "kernel.place")) == \
+        n_dispatch - 1
+    assert _parents(syncs[-1], evs, "kernel.extract")
+    assert len(named["grid.wait"]) == len(syncs)
+    assert all(len(_parents(e, evs, "grid.host_sync")) == 1
+               for e in named["grid.wait"])
+
+
+def test_host_sync_waits_apart_only_when_traced(monkeypatch):
+    from repro.core.comefa import grid as grid_mod
+
+    waits = []
+    real = grid_mod.jax.block_until_ready
+    monkeypatch.setattr(grid_mod.jax, "block_until_ready",
+                        lambda x: waits.append(1) or real(x))
+    grid = ComefaGrid(2, n_blocks=1, engine="packed")
+    for traced in (False, True):
+        trace.configure(enabled=traced)
+        grid.run(_mul_prog())
+        grid.mem                              # noqa: B018 - the sync
+    assert len(waits) == 1
+    names = [e.name for e in trace.get_tracer().events()
+             if e.name in ("grid.wait", "grid.host_sync")]
+    assert names == ["grid.wait", "grid.host_sync"]
+
+
+# ---------------------------------------------------------------------------
+# bytes across the host boundary
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["array", "grid"])
+def test_transfer_bytes_are_the_packed_state(kind, monkeypatch):
+    """Packed state is uint32 ``[G, nb, 128, 5]`` plus carry and mask
+    ``[G, nb, 5]``: 4 * G * nb * (128*5 + 2*5) bytes each way."""
+    monkeypatch.setattr(block, "_DEVICE_MAT_CACHE", {})
+    nb, g = 2, 3
+    if kind == "grid":
+        target = ComefaGrid(g, n_blocks=nb, engine="packed")
+        first = target.slot(0)
+    else:
+        g = 1
+        target = first = ComefaArray(n_blocks=nb, engine="packed")
+    state = 4 * g * nb * (128 * 5 + 2 * 5)
+    prog = _mul_prog()
+    a = np.arange(nb * 160).reshape(nb, 160) % (1 << BITS)
+    layout.place(first, a, 0, BITS)
+    tb = metrics.counter("comefa.transfer_bytes")
+
+    def moved(direction, what):
+        return tb.value(kind=kind, dir=direction, what=what)
+
+    target.run(prog)
+    assert moved("h2d", "state") == state
+    assert moved("h2d", "program") == block.encoded(prog).nbytes
+    assert moved("d2h", "state") == 0
+    layout.extract(first, 2 * BITS, 2 * BITS)
+    assert moved("d2h", "state") == state
+    target.run(prog)                          # re-upload; program cached
+    target.run(prog)                          # state stays on the device
+    assert moved("h2d", "state") == 2 * state
+    assert moved("h2d", "program") == block.encoded(prog).nbytes
+    other = "grid" if kind == "array" else "array"
+    assert not any(dict(key).get("kind") == other for key in tb.series())
