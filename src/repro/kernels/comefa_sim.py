@@ -462,16 +462,53 @@ def _broadcast_quote(k: int, n: int, w_bits: int, x_bits: int,
     return _BCAST_QUOTES[key]
 
 
+def _place_weights(mem: np.ndarray, w: np.ndarray, plan: schedule.GemvPlan,
+                   tile: schedule.GemvTile) -> None:
+    """Write one tile's weights for every slot at once into the stacked
+    grid state ``mem[G, nb, 128, 160]``.
+
+    Element j of the tile goes transposed, LSB first, to its rows of the
+    tile's buffer; output lane i lies in block ``i // 160``, and the lanes
+    past ``n`` are written as zeros.  The bytes are those of a
+    `layout.place` per slot and element of the zero-padded weight row.
+    """
+    G, _, n = w.shape
+    ne, nb, w_bits = tile.n_elems, plan.n_blocks, plan.w_bits
+    # the narrowest unsigned type holding w_bits keeps the low bits that
+    # `layout.to_bits` takes (two's complement wraps modulo its width)
+    wt = np.zeros((G, ne, nb * N_COLS),
+                  dtype=np.min_scalar_type((1 << w_bits) - 1))
+    wt[:, :, :n] = w[:, tile.k_start:tile.k_end, :]
+    wt = wt.reshape(G, ne, nb, N_COLS).transpose(0, 2, 1, 3)
+    planes = (wt[:, :, :, None, :]
+              >> np.arange(w_bits, dtype=wt.dtype)[:, None]) & 1
+    # operands are contiguous row ranges, so the buffer's first ne * w_bits
+    # rows are element j's ``weight_rows(j).base`` up, j < ne, in turn
+    rows = plan.buffers[tile.buffer].rows[:ne * w_bits]
+    mem[:, :, rows, :] = planes.reshape(G, nb, ne * w_bits, N_COLS)
+
+
+def _place_x(mem: np.ndarray, x: np.ndarray, plan: schedule.GemvPlan,
+             tile: schedule.GemvTile, x_rows) -> None:
+    """Broadcast each slot's activation bits of one tile over every block
+    and lane of that slot: element j's bits, LSB first, at ``x_rows[j]``."""
+    G = x.shape[0]
+    ne = tile.n_elems
+    xt = x[:, tile.k_start:tile.k_end].astype(np.int64)
+    assert ((0 <= xt) & (xt < (1 << plan.x_bits))).all()
+    bits = ((xt[:, :, None] >> np.arange(plan.x_bits)) & 1).astype(np.uint8)
+    rows = [r for op in x_rows[:ne] for r in op]
+    mem[:, :, rows, :] = bits.reshape(G, 1, ne * plan.x_bits, 1)
+
+
 def _extract_batched(grid: ComefaGrid, base: int, acc_bits: int,
                      n: int) -> np.ndarray:
-    """Every slot's first `n` accumulator lanes, ``[G, n]``, in a
-    ``kernel.extract`` span (after ``kernel.gemv_batched`` has closed)."""
-    out = np.empty((grid.g, n), dtype=np.int64)
+    """Every slot's first `n` accumulator lanes, ``[G, n]``, unsigned, in
+    a ``kernel.extract`` span (after ``kernel.gemv_batched`` has closed)."""
     with obs_trace.span("kernel.extract"):
-        for g in range(grid.g):
-            vals = layout.extract(grid.slot(g), base, acc_bits)
-            out[g] = vals.reshape(-1)[:n]
-    return out
+        planes = grid.mem[:, :, base:base + acc_bits, :].astype(np.int64)
+        vals = (planes << np.arange(acc_bits)[:, None]).sum(axis=2)
+        return vals.reshape(grid.g, -1)[:, :n]
 
 
 def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
@@ -549,25 +586,15 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     plan = schedule.cached_plan_gemv(k, n, w_bits, x_bits, acc_bits,
                                      k_tile=min(k, k_tile))
     x_rows = _gemv_batched_layout(plan)
-    nb, lanes = plan.n_blocks, N_COLS
-    pad = nb * lanes - n
-    grid = ComefaGrid(G, n_blocks=nb, mesh=mesh, engine=engine)
+    grid = ComefaGrid(G, n_blocks=plan.n_blocks, mesh=mesh, engine=engine)
     costs = []
     with obs_trace.span("kernel.gemv_batched", slots=G, k=k, n=n,
                         mode="broadcast") as sp:
         for tile in plan.tiles():
-            buf = plan.buffers[tile.buffer]
             with obs_trace.span("kernel.place"):
-                for g in range(G):
-                    slot = grid.slot(g)
-                    for j_local, j in enumerate(range(tile.k_start,
-                                                      tile.k_end)):
-                        wj = np.pad(w[g, j], (0, pad)).reshape(nb, lanes)
-                        rows = buf.weight_rows(j_local, w_bits)
-                        layout.place(slot, wj, rows.base, w_bits)
-                        assert 0 <= int(x[g, j]) < (1 << x_bits)
-                        layout.place(slot, np.full(lanes, int(x[g, j])),
-                                     x_rows[j_local].base, x_bits)
+                mem = grid.mem
+                _place_weights(mem, w, plan, tile)
+                _place_x(mem, x, plan, tile, x_rows)
             prog = _gemv_batched_chunk_program(plan, tile, x_rows,
                                                optimized=optimized)
             grid.run(prog)
@@ -607,22 +634,13 @@ def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     reserve = recode == "auto" or ir_mod.recode_is_signed(recode)
     plan = schedule.cached_plan_gemv(k, n, w_bits, x_bits, acc_bits,
                                      reserve_neg=reserve)
-    nb, lanes = plan.n_blocks, N_COLS
-    pad = nb * lanes - n
-    grid = ComefaGrid(G, n_blocks=nb, mesh=mesh, engine=engine)
+    grid = ComefaGrid(G, n_blocks=plan.n_blocks, mesh=mesh, engine=engine)
     costs = [[] for _ in range(G)]
     with obs_trace.span("kernel.gemv_batched", slots=G, k=k, n=n,
                         mode="per_slot", recode=recode) as sp:
         for tile in plan.tiles():
-            buf = plan.buffers[tile.buffer]
             with obs_trace.span("kernel.place"):
-                for g in range(G):
-                    slot = grid.slot(g)
-                    for j_local, j in enumerate(range(tile.k_start,
-                                                      tile.k_end)):
-                        wj = np.pad(w[g, j], (0, pad)).reshape(nb, lanes)
-                        rows = buf.weight_rows(j_local, w_bits)
-                        layout.place(slot, wj, rows.base, w_bits)
+                _place_weights(grid.mem, w, plan, tile)
             progs = [
                 plan.tile_program(
                     tile, x[g, tile.k_start:tile.k_end],

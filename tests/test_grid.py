@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ImportError:
     # no hypothesis in this environment (the container image has no pip):
     # fall back to the deterministic seeded sampler (tests/_minihyp.py)
@@ -305,18 +305,32 @@ def test_comefa_gemm_batched_matches_numpy_per_slot(g, k, bits, seed):
         np.testing.assert_array_equal(got[i], a[i] @ b[i])
 
 
+# modelled broadcast cycles (value-independent) of shapes pinned to what the
+# per-element placement loop gave: k 23 is three 7-element tiles and a short
+# 2-element one, n 330 spans 3 blocks with 150 padding lanes
+_PINNED_BCAST_CYCLES = {(23, 330): 2657}
+
+
 @given(g=st.sampled_from([1, 4]), k=st.sampled_from([1, 5, 19]),
        n=st.sampled_from([1, 40, 200]), seed=SEEDS)
+@example(g=4, k=23, n=330, seed=2214000001)
 @settings(max_examples=5, deadline=None)
 def test_comefa_gemv_batched_matches_numpy_per_slot(g, k, n, seed):
     from repro.kernels import comefa_sim
     rng = np.random.default_rng(seed)
-    w_bits, x_bits = 4, 5
+    w_bits, x_bits, acc_bits = 4, 5, 24
     w = rng.integers(0, 1 << w_bits, size=(g, k, n))
     x = rng.integers(0, 1 << x_bits, size=(g, k))
+    stats = {}
     got = comefa_sim.comefa_gemv_batched(w, x, w_bits=w_bits, x_bits=x_bits,
-                                         acc_bits=24)
+                                         acc_bits=acc_bits, stats=stats)
     assert got.shape == (g, n)
+    quote = comefa_sim._broadcast_quote(k, n, w_bits, x_bits, acc_bits,
+                                        optimized=True)
+    assert stats == {"mode": "broadcast",
+                     "cycles": sum(quote.compute_cycles)}
+    if (k, n) in _PINNED_BCAST_CYCLES:
+        assert stats["cycles"] == _PINNED_BCAST_CYCLES[k, n]
     for i in range(g):
         np.testing.assert_array_equal(got[i], w[i].T.astype(np.int64)
                                       @ x[i].astype(np.int64))
