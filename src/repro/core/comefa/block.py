@@ -70,7 +70,8 @@ _F = {name: i for i, name in enumerate(isa.ENGINE_FIELD_NAMES)}
 #   comefa.host_syncs / comefa.device_puts {kind=array|grid}
 #   comefa.dispatches / comefa.dispatch_cycles {kind=..., engine=...}
 #   comefa.engine_select{engine=...}
-#   comefa.transfer_bytes{kind=array|grid, dir=h2d|d2h, what=state|program}
+#   comefa.transfer_bytes{kind=array|grid, dir=h2d|d2h|d2d,
+#                         what=state|program|rows}
 _ENCODE_EVENTS = obs_metrics.counter("comefa.encode_cache")
 _HOST_SYNCS = obs_metrics.counter("comefa.host_syncs")
 _DEVICE_PUTS = obs_metrics.counter("comefa.device_puts")
@@ -81,8 +82,9 @@ _TRANSFER_BYTES = obs_metrics.counter("comefa.transfer_bytes")
 
 
 def count_transfer(arrays, kind: str, direction: str, what: str) -> None:
-    """Count the bytes of `arrays` as one host<->device crossing
-    (``direction`` is ``"h2d"`` or ``"d2h"``)."""
+    """Count the bytes of `arrays` as one crossing: ``direction`` is
+    ``"h2d"`` or ``"d2h"``, or ``"d2d"`` for device rows written into
+    device state."""
     _TRANSFER_BYTES.inc(sum(int(a.nbytes) for a in arrays), kind=kind,
                         dir=direction, what=what)
 
@@ -276,7 +278,9 @@ class _ReferenceEngine:
     Engine protocol (shared with `engine_packed`): `to_device` lifts host
     uint8 state into the engine's device representation, `run` /
     `run_per_slot` advance it (device-to-device, no host copies), and
-    `to_host` materializes writable numpy uint8 state back.
+    `to_host` materializes writable numpy uint8 state back; `pack_rows` /
+    `unpack_rows` convert rows between 0/1 bits and that representation
+    on the device.
     """
 
     name = "reference"
@@ -289,6 +293,15 @@ class _ReferenceEngine:
         # device buffers, and callers mutate the result in place (port
         # writes, `layout` placements between runs)
         return tuple(np.array(x) for x in state)
+
+    def pack_rows(self, bits):
+        """Device bit rows ``[..., C]`` of 0/1 in this engine's format
+        (traceable; uint8 here, one lane per byte)."""
+        return bits.astype(jnp.uint8)
+
+    def unpack_rows(self, rows):
+        """Inverse of `pack_rows`: engine-format rows -> uint8 0/1 bits."""
+        return rows
 
     def run(self, state, prog, chain: bool):
         return _run(*state, prog, chain)

@@ -273,6 +273,18 @@ class PackedXlaEngine:
         mem, carry, mask = (np.array(x) for x in state)
         return unpack_bits(mem), unpack_bits(carry), unpack_bits(mask)
 
+    def pack_rows(self, bits):
+        """Device bit rows ``[..., C]`` of 0/1 -> packed uint32
+        ``[..., C // 32]``: `pack_bits` in jnp, traceable."""
+        b = bits.astype(jnp.uint32).reshape(bits.shape[:-1] + (-1, PACK))
+        return jnp.sum(b << jnp.asarray(_SHIFTS), axis=-1, dtype=jnp.uint32)
+
+    def unpack_rows(self, rows):
+        """Packed rows ``[..., W]`` -> uint8 bits ``[..., W * 32]``:
+        `unpack_bits` in jnp, traceable."""
+        bits = (rows[..., None] >> jnp.asarray(_SHIFTS)) & jnp.uint32(1)
+        return bits.astype(jnp.uint8).reshape(rows.shape[:-1] + (-1,))
+
     def run(self, state, prog, chain: bool):
         return _run_packed(*state, prog, chain)
 

@@ -32,6 +32,7 @@ chained) block row.
 """
 from __future__ import annotations
 
+import functools
 from typing import List, Sequence, Tuple
 
 import jax
@@ -58,6 +59,23 @@ from .isa import N_COLS, N_ROWS, ROW_ONES
 # the modelled hardware *saves* cycles (zero-skipping returns).
 _run_grid = block._run
 _run_slotwise = block._run_slotwise
+
+
+# row ranges of engine-format state; the row axis is second to last in
+# the uint8 reference state ``[..., R, C]`` and the packed ``[..., R, W]``
+@functools.partial(jax.jit, static_argnames=("base",))
+def _write_rows(mem, planes, base: int):
+    """`mem` with rows ``base .. base + n`` replaced by `planes`."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        mem, planes.astype(mem.dtype), base, axis=mem.ndim - 2)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
+def _read_rows(unpack_rows, mem, base: int, n: int, lane_step: int):
+    """Rows ``base .. base + n`` of engine-format `mem` as 0/1 bits, at
+    every `lane_step`-th lane."""
+    rows = jax.lax.slice_in_dim(mem, base, base + n, axis=mem.ndim - 2)
+    return unpack_rows(rows)[..., ::lane_step]
 
 
 # per-slot program matrices are padded up to a multiple of this quantum so
@@ -194,6 +212,47 @@ class ComefaGrid:
     def mask(self, value):
         self._sync_host()
         self._mask = np.asarray(value)
+
+    @property
+    def device_state(self):
+        """The engine-format device state tuple, or None while the host
+        copy is current: a handle to wait on a dispatch without a copy."""
+        return self._dev
+
+    def write_rows(self, base: int, planes) -> None:
+        """Overwrite rows ``base .. base + n`` of every slot and block.
+
+        `planes` is a device array in the engine's format for those rows,
+        ``[G, n_blocks, n, lanes]`` (`engine.pack_rows` makes it from 0/1
+        bits).  The write happens on the device: state already there is
+        neither synced to the host nor uploaded again.  The reserved
+        constant rows stay as they are.
+        """
+        n = int(planes.shape[-2])
+        if (tuple(planes.shape[:2]) != (self.g, self.n_blocks) or base < 0
+                or base + n > isa.USABLE_ROWS):
+            raise ValueError(f"rows {base}..{base + n} of planes "
+                             f"{planes.shape}: not a row range below the "
+                             "reserved rows of every slot and block")
+        with obs_trace.span("grid.write_rows", rows=n):
+            self._ensure_device(self._active_engine())
+            mem, carry, mask = self._dev
+            self._dev = (_write_rows(mem, planes, base), carry, mask)
+        block.count_transfer((planes,), "grid", "d2d", "rows")
+
+    def read_rows(self, base: int, n: int, lane_step: int = 1) -> np.ndarray:
+        """Bits of rows ``base .. base + n`` at lanes 0, `lane_step`,
+        2 `lane_step`, ... of every slot and block, ``[G, n_blocks, n,
+        lanes]`` uint8.  Only those bits leave the device (unpacked and
+        picked out there); the state stays put and no host sync happens."""
+        if self._dev is None:
+            return self._mem[:, :, base:base + n, ::lane_step].copy()
+        with obs_trace.span("grid.read_rows", rows=n):
+            rows = _read_rows(self._active_engine().unpack_rows,
+                              self._dev[0], base, n, lane_step)
+            bits = np.array(rows)
+        block.count_transfer((rows,), "grid", "d2h", "rows")
+        return bits
 
     def slot(self, g: int) -> _Slot:
         """Array-like view of slot g (usable with `layout` helpers)."""

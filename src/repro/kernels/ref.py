@@ -53,3 +53,23 @@ def bit_transpose_ref(x: np.ndarray, bits: int) -> np.ndarray:
         planes[i] = (b << np.arange(32, dtype=np.uint32)).sum(
             axis=1).astype(np.uint32)
     return planes
+
+
+def q6_revenue_ref(cols, year: int, discount: int, quantity: int) -> int:
+    """TPC-H Q6 in int64 numpy: sum(price * discount) over the rows with
+    January 1 of `year` <= shipdate < January 1 of `year + 1`,
+    discount - 1 <= l_discount <= discount + 1 and l_quantity < quantity.
+
+    `cols` maps shipdate (days since 1992-01-01), discount (hundredths),
+    quantity and price (cents) to integer arrays; the revenue is in
+    1/10,000 dollar, exact.
+    """
+    epoch = np.datetime64("1992-01-01", "D")
+    lo = int((np.datetime64(f"{year:04d}-01-01", "D") - epoch).astype(int))
+    hi = int((np.datetime64(f"{year + 1:04d}-01-01", "D") - epoch)
+             .astype(int))
+    ship, disc = cols["shipdate"], cols["discount"]
+    keep = ((ship >= lo) & (ship < hi) & (disc >= discount - 1)
+            & (disc <= discount + 1) & (cols["quantity"] < quantity))
+    price = np.asarray(cols["price"])[keep].astype(np.int64)
+    return int(np.sum(price * np.asarray(disc)[keep].astype(np.int64)))
