@@ -71,7 +71,7 @@ _F = {name: i for i, name in enumerate(isa.ENGINE_FIELD_NAMES)}
 #   comefa.dispatches / comefa.dispatch_cycles {kind=..., engine=...}
 #   comefa.engine_select{engine=...}
 #   comefa.transfer_bytes{kind=array|grid, dir=h2d|d2h|d2d,
-#                         what=state|program|rows}
+#                         what=state|program|rows|weights|x}
 _ENCODE_EVENTS = obs_metrics.counter("comefa.encode_cache")
 _HOST_SYNCS = obs_metrics.counter("comefa.host_syncs")
 _DEVICE_PUTS = obs_metrics.counter("comefa.device_puts")

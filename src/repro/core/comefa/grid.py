@@ -70,6 +70,18 @@ def _write_rows(mem, planes, base: int):
         mem, planes.astype(mem.dtype), base, axis=mem.ndim - 2)
 
 
+@functools.partial(jax.jit, static_argnames=("bases",))
+def _write_row_ranges(mem, planes, bases):
+    """`mem` with rows ``base .. base + n`` of each range replaced by its
+    planes, broadcast over the leading axes they leave out."""
+    axis = mem.ndim - 2
+    for p, base in zip(planes, bases):
+        p = jnp.broadcast_to(p.astype(mem.dtype),
+                             mem.shape[:axis] + p.shape[-2:])
+        mem = jax.lax.dynamic_update_slice_in_dim(mem, p, base, axis=axis)
+    return mem
+
+
 @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
 def _read_rows(unpack_rows, mem, base: int, n: int, lane_step: int):
     """Rows ``base .. base + n`` of engine-format `mem` as 0/1 bits, at
@@ -239,6 +251,36 @@ class ComefaGrid:
             mem, carry, mask = self._dev
             self._dev = (_write_rows(mem, planes, base), carry, mask)
         block.count_transfer((planes,), "grid", "d2d", "rows")
+
+    def write_row_ranges(self, ranges: Sequence[Tuple[int, object]]) -> None:
+        """Overwrite several row ranges of every slot and block in one
+        device call, as a `write_rows` of each ``(base, planes)`` in turn.
+
+        `planes` may take any shape that broadcasts to ``[G, n_blocks, n,
+        lanes]``: ``[n_blocks, n, lanes]`` writes the same rows into every
+        slot, ``[G, 1, n, lanes]`` the same rows into every block of a
+        slot.  Each range's bytes count as given, before the broadcast.
+        """
+        bases, planes = [], []
+        for base, p in ranges:
+            n = int(p.shape[-2])
+            lead = tuple(p.shape[:-2])
+            if (len(lead) > 2 or base < 0 or base + n > isa.USABLE_ROWS
+                    or np.broadcast_shapes(lead, (self.g, self.n_blocks))
+                    != (self.g, self.n_blocks)):
+                raise ValueError(f"rows {base}..{base + n} of planes "
+                                 f"{p.shape}: not a row range below the "
+                                 "reserved rows that broadcasts to every "
+                                 "slot and block")
+            bases.append(base)
+            planes.append(p)
+        with obs_trace.span("grid.write_rows",
+                            rows=sum(int(p.shape[-2]) for p in planes)):
+            self._ensure_device(self._active_engine())
+            mem, carry, mask = self._dev
+            self._dev = (_write_row_ranges(mem, tuple(planes), tuple(bases)),
+                         carry, mask)
+        block.count_transfer(planes, "grid", "d2d", "rows")
 
     def read_rows(self, base: int, n: int, lane_step: int = 1) -> np.ndarray:
         """Bits of rows ``base .. base + n`` at lanes 0, `lane_step`,

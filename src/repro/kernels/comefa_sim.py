@@ -22,12 +22,15 @@ problems through `core.comefa.schedule`'s double-buffered LCU plans.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+import functools
+from typing import Dict, Optional, Tuple, Union
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ..core.comefa import (ComefaArray, ComefaGrid, N_COLS, layout, program,
-                           schedule)
+from ..core.comefa import (ComefaArray, ComefaGrid, N_COLS, block, layout,
+                           program, schedule)
 from ..core.comefa import ir as ir_mod
 from ..core.comefa import recode as recode_mod
 from ..core.comefa.ir import Program, RowAllocator
@@ -462,56 +465,149 @@ def _broadcast_quote(k: int, n: int, w_bits: int, x_bits: int,
     return _BCAST_QUOTES[key]
 
 
-def _place_weights(mem: np.ndarray, w: np.ndarray, plan: schedule.GemvPlan,
-                   tile: schedule.GemvTile) -> None:
-    """Write one tile's weights for every slot at once into the stacked
-    grid state ``mem[G, nb, 128, 160]``.
+# comefa.weight_planes{event=build|reuse}: a GEMV's weight bit planes made
+# on the device, or found there already by a later call
+_WEIGHT_PLANES = obs_metrics.counter("comefa.weight_planes")
 
-    Element j of the tile goes transposed, LSB first, to its rows of the
-    tile's buffer; output lane i lies in block ``i // 160``, and the lanes
-    past ``n`` are written as zeros.  The bytes are those of a
-    `layout.place` per slot and element of the zero-padded weight row.
+
+def _unsigned(values: np.ndarray, bits: int) -> np.ndarray:
+    """`values` in the narrowest unsigned type holding `bits` bits, which
+    keeps the low bits that `layout.to_bits` takes (two's complement wraps
+    modulo its width)."""
+    return values.astype(np.min_scalar_type((1 << bits) - 1))
+
+
+def _row_base(rows) -> int:
+    """First row of `rows`, which must be one ascending contiguous range
+    (so that one row-range write covers them)."""
+    rows = list(rows)
+    if rows != list(range(rows[0], rows[0] + len(rows))):
+        raise ValueError(f"rows {rows} are not one contiguous range")
+    return rows[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _weight_bases(plan: schedule.GemvPlan) -> Tuple[int, ...]:
+    """Each buffer's first weight row: operands are contiguous row ranges,
+    so element j of a tile holds rows ``base + j * w_bits`` up, in turn."""
+    return tuple(_row_base(buf.rows[:plan.k_tile * plan.w_bits])
+                 for buf in plan.buffers)
+
+
+@functools.lru_cache(maxsize=None)
+def _weight_planer(pack_rows, plan: schedule.GemvPlan):
+    """Jitted: weights ``[..., k, n]`` in `_unsigned`'s narrow type -> one
+    engine-format plane block per tile, ``[..., nb, ne * w_bits, lanes]``.
+
+    Element j of a tile goes transposed, LSB first, to rows ``j * w_bits``
+    up of its block; output lane i lies in block ``i // 160``, and the
+    lanes past ``n`` are zeros.  The bits are those of a `layout.place` per
+    slot and element of the zero-padded weight row.
     """
-    G, _, n = w.shape
-    ne, nb, w_bits = tile.n_elems, plan.n_blocks, plan.w_bits
-    # the narrowest unsigned type holding w_bits keeps the low bits that
-    # `layout.to_bits` takes (two's complement wraps modulo its width)
-    wt = np.zeros((G, ne, nb * N_COLS),
-                  dtype=np.min_scalar_type((1 << w_bits) - 1))
-    wt[:, :, :n] = w[:, tile.k_start:tile.k_end, :]
-    wt = wt.reshape(G, ne, nb, N_COLS).transpose(0, 2, 1, 3)
-    planes = (wt[:, :, :, None, :]
-              >> np.arange(w_bits, dtype=wt.dtype)[:, None]) & 1
-    # operands are contiguous row ranges, so the buffer's first ne * w_bits
-    # rows are element j's ``weight_rows(j).base`` up, j < ne, in turn
-    rows = plan.buffers[tile.buffer].rows[:ne * w_bits]
-    mem[:, :, rows, :] = planes.reshape(G, nb, ne * w_bits, N_COLS)
+    nb, wb = plan.n_blocks, plan.w_bits
+
+    def build(w):
+        lead = w.shape[:-2]
+        w = jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, nb * N_COLS - plan.n)])
+        w = w.reshape(lead + (plan.k, nb, 1, N_COLS))
+        bits = (w >> jnp.arange(wb, dtype=w.dtype)[:, None]) & 1
+        bits = jnp.moveaxis(bits, -4, -3)             # [..., nb, k, wb, C]
+        return tuple(
+            pack_rows(bits[..., t.k_start:t.k_end, :, :].reshape(
+                lead + (nb, t.n_elems * wb, N_COLS)))
+            for t in plan.tiles())
+    return jax.jit(build)
 
 
-def _place_x(mem: np.ndarray, x: np.ndarray, plan: schedule.GemvPlan,
-             tile: schedule.GemvTile, x_rows) -> None:
-    """Broadcast each slot's activation bits of one tile over every block
-    and lane of that slot: element j's bits, LSB first, at ``x_rows[j]``."""
-    G = x.shape[0]
-    ne = tile.n_elems
-    xt = x[:, tile.k_start:tile.k_end].astype(np.int64)
-    assert ((0 <= xt) & (xt < (1 << plan.x_bits))).all()
-    bits = ((xt[:, :, None] >> np.arange(plan.x_bits)) & 1).astype(np.uint8)
-    rows = [r for op in x_rows[:ne] for r in op]
-    mem[:, :, rows, :] = bits.reshape(G, 1, ne * plan.x_bits, 1)
+@functools.lru_cache(maxsize=None)
+def _x_planer(pack_rows, plan: schedule.GemvPlan):
+    """Jitted: activations ``[G, k]`` in `_unsigned`'s narrow type -> per
+    tile, each slot's bits broadcast over every lane, ``[G, 1, ne * x_bits,
+    lanes]``: element j's bits, LSB first, at rows ``j * x_bits`` up."""
+    xb = plan.x_bits
+
+    def build(x):
+        g = x.shape[0]
+        bits = (x[:, :, None] >> jnp.arange(xb, dtype=x.dtype)) & 1
+        return tuple(
+            pack_rows(jnp.broadcast_to(
+                bits[:, t.k_start:t.k_end].reshape(g, 1, -1, 1),
+                (g, 1, t.n_elems * xb, N_COLS)))
+            for t in plan.tiles())
+    return jax.jit(build)
+
+
+class GemvWeights:
+    """The weights of a batched GEMV and their bit planes on the device.
+
+    `w` is ``[k, n]``, shared by every slot, or ``[G, k, n]``, a matrix
+    per slot.  `planes` builds every tile's planes on the device once per
+    plan and engine (`comefa.weight_planes{event=build}`) and keeps them,
+    so a caller that keeps this object across calls - the serving
+    executor, whose weights never change - finds them there
+    (``{event=reuse}``).  Values outside ``w_bits`` keep their low bits.
+    """
+
+    def __init__(self, w):
+        self.w = np.asarray(w)
+        if self.w.ndim not in (2, 3):
+            raise ValueError(f"weights {self.w.shape}: [k, n] or "
+                             "[G, k, n] expected")
+        self._planes: Dict[Tuple, tuple] = {}
+
+    def planes(self, plan: schedule.GemvPlan, engine) -> tuple:
+        """Each tile's weight planes in `engine`'s format,
+        ``[(G,) nb, ne * w_bits, lanes]``."""
+        key = (plan, engine)
+        planes = self._planes.get(key)
+        if planes is not None:
+            _WEIGHT_PLANES.inc(event="reuse")
+            return planes
+        w = _unsigned(self.w, plan.w_bits)
+        planes = _weight_planer(engine.pack_rows, plan)(w)
+        block.count_transfer((w,), "grid", "h2d", "weights")
+        _WEIGHT_PLANES.inc(event="build")
+        self._planes[key] = planes
+        return planes
+
+
+def _x_planes(x: np.ndarray, plan: schedule.GemvPlan, engine) -> tuple:
+    """Every tile's activation planes (`_x_planer`), from one upload of
+    the whole ``[G, k]`` activations."""
+    if x.min() < 0 or x.max() >= 1 << plan.x_bits:
+        raise ValueError(f"activations outside 0..{(1 << plan.x_bits) - 1}")
+    xu = _unsigned(x, plan.x_bits)
+    block.count_transfer((xu,), "grid", "h2d", "x")
+    return _x_planer(engine.pack_rows, plan)(xu)
+
+
+def _place_tile(grid: ComefaGrid, plan: schedule.GemvPlan,
+                tile: schedule.GemvTile, w_planes: tuple,
+                x_planes: Optional[tuple] = None, x_base: int = 0) -> None:
+    """Write one tile's weight planes, and on the broadcast path its
+    activation planes, into every slot: one device-side row write in a
+    ``kernel.place`` span.  Rows past the tile's elements keep what they
+    held; its program does not read them."""
+    ranges = [(_weight_bases(plan)[tile.buffer], w_planes[tile.index])]
+    if x_planes is not None:
+        ranges.append((x_base, x_planes[tile.index]))
+    with obs_trace.span("kernel.place"):
+        grid.write_row_ranges(ranges)
 
 
 def _extract_batched(grid: ComefaGrid, base: int, acc_bits: int,
                      n: int) -> np.ndarray:
     """Every slot's first `n` accumulator lanes, ``[G, n]``, unsigned, in
-    a ``kernel.extract`` span (after ``kernel.gemv_batched`` has closed)."""
+    a ``kernel.extract`` span (after ``kernel.gemv_batched`` has closed):
+    one `read_rows` of the accumulator, no state sync."""
     with obs_trace.span("kernel.extract"):
-        planes = grid.mem[:, :, base:base + acc_bits, :].astype(np.int64)
+        planes = grid.read_rows(base, acc_bits).astype(np.int64)
         vals = (planes << np.arange(acc_bits)[:, None]).sum(axis=2)
         return vals.reshape(grid.g, -1)[:, :n]
 
 
-def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
+def comefa_gemv_batched(w: Union[np.ndarray, GemvWeights], x: np.ndarray,
+                        *, w_bits: int,
                         x_bits: int, acc_bits: int = 32,
                         optimized: bool = True, mesh=None,
                         recode: Optional[str] = None,
@@ -519,7 +615,12 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
                         engine=None) -> np.ndarray:
     """y[g] = w[g].T @ x[g] for G independent GEMVs on ONE grid dispatch.
 
-    w: [G, k, n], x: [G, k] unsigned ints.  Two execution modes:
+    w: [G, k, n] unsigned ints, or [k, n] shared by every slot, or a
+    `GemvWeights` whose device planes a caller keeps across calls; x:
+    [G, k] unsigned ints.  Each tile's weight and activation planes are
+    written on the device and the accumulator is read with one
+    `ComefaGrid.read_rows`: the grid state stays on the device for the
+    whole call.  Two execution modes:
 
       * ``recode=None`` (the shared-FSM broadcast): geometry from the
         same `schedule.plan_gemv` double-buffered chunking as
@@ -555,11 +656,12 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     registry - prefer that for new callers; the ``stats`` side channel
     is kept for compatibility.
     """
-    w = np.asarray(w)
+    weights = w if isinstance(w, GemvWeights) else GemvWeights(w)
     x = np.asarray(x)
-    assert w.ndim == 3 and x.ndim == 2 and w.shape[0] == x.shape[0]
-    assert w.shape[1] == x.shape[1]
-    G, k, n = w.shape
+    assert x.ndim == 2 and x.shape[1] == weights.w.shape[-2]
+    assert weights.w.ndim == 2 or weights.w.shape[0] == x.shape[0]
+    G = x.shape[0]
+    k, n = weights.w.shape[-2:]
     choices = None
     if recode == "auto":
         plan_ps = schedule.cached_plan_gemv(k, n, w_bits, x_bits, acc_bits,
@@ -572,7 +674,8 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
         else:
             choices = sel.choices
     if recode is not None:
-        return _comefa_gemv_per_slot(w, x, w_bits=w_bits, x_bits=x_bits,
+        return _comefa_gemv_per_slot(weights, x, w_bits=w_bits,
+                                     x_bits=x_bits,
                                      acc_bits=acc_bits, optimized=optimized,
                                      mesh=mesh, recode=recode,
                                      choices=choices, stats=stats,
@@ -586,15 +689,15 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     plan = schedule.cached_plan_gemv(k, n, w_bits, x_bits, acc_bits,
                                      k_tile=min(k, k_tile))
     x_rows = _gemv_batched_layout(plan)
+    x_base = _row_base(r for op in x_rows for r in op)
     grid = ComefaGrid(G, n_blocks=plan.n_blocks, mesh=mesh, engine=engine)
     costs = []
     with obs_trace.span("kernel.gemv_batched", slots=G, k=k, n=n,
                         mode="broadcast") as sp:
+        w_planes = weights.planes(plan, grid.engine)
+        x_planes = _x_planes(x, plan, grid.engine)
         for tile in plan.tiles():
-            with obs_trace.span("kernel.place"):
-                mem = grid.mem
-                _place_weights(mem, w, plan, tile)
-                _place_x(mem, x, plan, tile, x_rows)
+            _place_tile(grid, plan, tile, w_planes, x_planes, x_base)
             prog = _gemv_batched_chunk_program(plan, tile, x_rows,
                                                optimized=optimized)
             grid.run(prog)
@@ -615,7 +718,8 @@ def comefa_gemv_batched(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     return _extract_batched(grid, plan.acc.base, acc_bits, n)
 
 
-def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
+def _comefa_gemv_per_slot(weights: GemvWeights, x: np.ndarray, *,
+                          w_bits: int,
                           x_bits: int, acc_bits: int, optimized: bool,
                           mesh, recode: str, choices=None,
                           stats: Optional[Dict] = None,
@@ -630,7 +734,8 @@ def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     runs its own pre-selected digit schedule - mixed recodes across
     slots are legal because every grid slice has its own FSM.
     """
-    G, k, n = w.shape
+    G = x.shape[0]
+    k, n = weights.w.shape[-2:]
     reserve = recode == "auto" or ir_mod.recode_is_signed(recode)
     plan = schedule.cached_plan_gemv(k, n, w_bits, x_bits, acc_bits,
                                      reserve_neg=reserve)
@@ -638,9 +743,9 @@ def _comefa_gemv_per_slot(w: np.ndarray, x: np.ndarray, *, w_bits: int,
     costs = [[] for _ in range(G)]
     with obs_trace.span("kernel.gemv_batched", slots=G, k=k, n=n,
                         mode="per_slot", recode=recode) as sp:
+        w_planes = weights.planes(plan, grid.engine)
         for tile in plan.tiles():
-            with obs_trace.span("kernel.place"):
-                _place_weights(grid.mem, w, plan, tile)
+            _place_tile(grid, plan, tile, w_planes)
             progs = [
                 plan.tile_program(
                     tile, x[g, tile.k_start:tile.k_end],

@@ -5,7 +5,7 @@ Runs a small per-slot-stream grid GEMV (`comefa_gemv_batched` with
 force-enabled and writes:
 
   * a Chrome trace-event JSON (wall-clock spans - encode, dispatch,
-    host sync - plus the per-tile load/compute/unload model-cycle spans
+    accumulator read - plus the per-tile load/compute/unload model-cycle spans
     of every slot's `Schedule`), loadable in Perfetto;
   * optionally a flat metrics dump (``--metrics PATH``).
 

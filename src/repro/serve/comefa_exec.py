@@ -130,10 +130,12 @@ class GridLinearExecutor:
 
     # -- weights -----------------------------------------------------------
     def _weights(self, packed, bits: int):
-        """Unpacked offset-encoded weights + per-column sums, cached.
+        """Unpacked offset-encoded weights, per-column sums and the grid's
+        `comefa_sim.GemvWeights` of them, cached.
 
         Params are immutable across decode steps, so the unpack runs once
-        per projection (keyed on the packed array's identity).
+        per projection (keyed on the packed array's identity), and the
+        weights' bit planes, once built on the device, stay there.
         """
         key = id(packed)
         ent = self._wcache.get(key)
@@ -141,9 +143,9 @@ class GridLinearExecutor:
             q = np.asarray(bitplane.unpack(packed, bits, axis=0),
                            np.int64)                       # [K, N] signed
             w_u = q + (1 << (bits - 1))                    # unsigned
-            ent = (packed, w_u, w_u.sum(axis=0))
+            ent = (packed, w_u, w_u.sum(axis=0), comefa_sim.GemvWeights(w_u))
             self._wcache[key] = ent
-        return ent[1], ent[2]
+        return ent[1:]
 
     # -- stats -------------------------------------------------------------
     def occupancy(self) -> float:
@@ -156,7 +158,7 @@ class GridLinearExecutor:
     def __call__(self, params, x2, bits: int):
         """hook(params, x2 [rows, K] float, bits) -> [rows, N] float32."""
         packed, scale = params["packed"], params["scale"]
-        w_u, col_sum = self._weights(packed, bits)
+        w_u, col_sum, w_grid = self._weights(packed, bits)
         k, n = w_u.shape
         xf = np.asarray(x2, np.float32)
         rows = xf.shape[0]
@@ -187,7 +189,7 @@ class GridLinearExecutor:
                 if self.backend == "grid":
                     stats: Dict = {}
                     acc[wave] = comefa_sim.comefa_gemv_batched(
-                        np.broadcast_to(w_u, (g, k, n)), x_u[wave],
+                        w_grid, x_u[wave],
                         w_bits=bits, x_bits=self.x_bits, acc_bits=acc_bits,
                         recode=self.recode, stats=stats, engine=self.engine)
                     self.grid_cycles += stats["cycles"]

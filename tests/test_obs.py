@@ -366,7 +366,7 @@ def test_metrics_summary_selection_visible_after_auto_gemv():
 def test_env_var_traced_sweep_produces_valid_trace(tmp_path, monkeypatch):
     """`REPRO_COMEFA_TRACE=...` + a run_per_slot GEMV sweep must yield a
     non-empty Chrome trace carrying BOTH time domains: wall-clock spans
-    (encode / dispatch / host sync) and the per-tile load/compute/unload
+    (encode / dispatch / accumulator read) and the per-tile load/compute/unload
     model-cycle spans of every slot's schedule."""
     from repro.kernels import comefa_sim
 
@@ -390,7 +390,7 @@ def test_env_var_traced_sweep_produces_valid_trace(tmp_path, monkeypatch):
     wall = {e["name"] for e in xs if e["pid"] == export.WALL_PID}
     assert "comefa.encode" in wall
     assert "grid.run_per_slot" in wall
-    assert "grid.host_sync" in wall
+    assert "grid.read_rows" in wall
     model = [e for e in xs if e["pid"] == export.MODEL_PID]
     assert {e["args"]["phase"] for e in model} == \
         {"load", "compute", "unload"}
@@ -547,16 +547,18 @@ def _parents(ev, events, name):
 
 
 @pytest.mark.parametrize("recode", [None, "naive"])
-def test_grid_host_spans_nest(recode):
+def test_grid_host_spans_nest(recode, monkeypatch):
+    from repro.core.comefa import isa, schedule
     from repro.kernels import comefa_sim
 
+    monkeypatch.setattr(block, "_DEVICE_MAT_CACHE", {})
     trace.configure(enabled=True)
     rng = np.random.default_rng(3)
-    g, k, n, wb, xb = 2, 48, 200, 3, 4
+    g, k, n, wb, xb, acc = 2, 48, 200, 3, 4, 16
     w = rng.integers(0, 1 << wb, size=(g, k, n))
     x = rng.integers(0, 1 << xb, size=(g, k))
     y = comefa_sim.comefa_gemv_batched(w, x, w_bits=wb, x_bits=xb,
-                                       acc_bits=16, recode=recode,
+                                       acc_bits=acc, recode=recode,
                                        engine="packed")
     assert np.array_equal(y, np.einsum("gkn,gk->gn", w, x))
     evs = [e for e in trace.get_tracer().events()
@@ -567,35 +569,68 @@ def test_grid_host_spans_nest(recode):
     kernel, = named["kernel.gemv_batched"]
     dispatch = "grid.run_per_slot" if recode else "grid.dispatch"
     n_dispatch = len(named[dispatch])
-    assert n_dispatch >= 2            # a later tile's placement syncs
-    # one placement span per tile, inside the kernel's span
-    assert len(named["kernel.place"]) == n_dispatch
-    assert all(_inside(e, kernel) for e in named["kernel.place"])
-    # the final extract closes after the kernel's span
+    assert n_dispatch >= 2
+    # one placement span per tile, inside the kernel's span, holding its
+    # device-side row write
+    places = named["kernel.place"]
+    assert len(places) == n_dispatch
+    assert all(_inside(e, kernel) for e in places)
+    assert len(named["grid.write_rows"]) == n_dispatch
+    assert all(_parents(e, evs, "kernel.place")
+               for e in named["grid.write_rows"])
+    assert all(1 <= sum(_inside(e, p) for e in named["grid.write_rows"]) <= 2
+               for p in places)
+    # the final extract closes after the kernel's span and reads the
+    # accumulator rows alone
     extract, = named["kernel.extract"]
     assert extract.ts >= kernel.ts + kernel.dur
-    # the first dispatch uploads the fresh state, each later one the state
-    # its tile's placement synced back
-    assert len(named["grid.upload"]) == n_dispatch
-    for name in ("grid.upload", "grid.program_upload"):
-        assert all(len(_parents(e, evs, dispatch)) == 1
-                   for e in named[name])
+    read, = named["grid.read_rows"]
+    assert _inside(read, extract)
+    # the state stays on the device: the fresh state uploads once, in the
+    # first placement's write, and nothing syncs it back
+    upload, = named["grid.upload"]
+    assert _inside(upload, places[0])
+    assert _parents(upload, evs, "grid.write_rows")
+    assert "grid.host_sync" not in named and "grid.wait" not in named
+    assert metrics.counter("comefa.host_syncs").value(kind="grid") == 0
     assert len(named["grid.program_upload"]) == n_dispatch
+    assert all(len(_parents(e, evs, dispatch)) == 1
+               for e in named["grid.program_upload"])
     if recode:
         assert len(named["grid.stack"]) == n_dispatch
         assert all(_parents(e, evs, dispatch) for e in named["grid.stack"])
     else:
         assert "grid.stack" not in named
-    # a host sync in every tile's placement but the first, and one in the
-    # extract; the wait for the device sits inside each
-    syncs = named["grid.host_sync"]
-    assert len(syncs) == n_dispatch
-    assert sum(1 for s in syncs if _parents(s, evs, "kernel.place")) == \
-        n_dispatch - 1
-    assert _parents(syncs[-1], evs, "kernel.extract")
-    assert len(named["grid.wait"]) == len(syncs)
-    assert all(len(_parents(e, evs, "grid.host_sync")) == 1
-               for e in named["grid.wait"])
+
+    # the bytes of the call, by direction and what they are: packed rows
+    # are 5 uint32 words of 160 lanes
+    if recode:
+        plan = schedule.cached_plan_gemv(k, n, wb, xb, acc)
+        programs = sum(g * e.attrs["padded_to"] * isa.N_ENGINE_FIELDS * 4
+                       for e in named[dispatch])
+    else:
+        plan = schedule.cached_plan_gemv(
+            k, n, wb, xb, acc,
+            k_tile=min(k, comefa_sim.gemv_batched_k_tile(wb, xb, acc)))
+        x_rows = comefa_sim._gemv_batched_layout(plan)
+        mats = {id(m): m.nbytes for m in (
+            block.encoded(comefa_sim._gemv_batched_chunk_program(
+                plan, t, x_rows, optimized=True)) for t in plan.tiles())}
+        programs = sum(mats.values())
+    nb, row = plan.n_blocks, 5 * 4
+    rows = sum(g * nb * t.n_elems * wb * row for t in plan.tiles())
+    want = {("h2d", "state"): 4 * g * nb * (128 * 5 + 2 * 5),
+            ("h2d", "weights"): g * k * n,           # uint8 weights
+            ("h2d", "program"): programs,
+            ("d2d", "rows"): rows,
+            ("d2h", "rows"): g * nb * acc * 160}     # uint8 bits
+    if not recode:
+        want["h2d", "x"] = g * k                     # uint8 activations
+        want["d2d", "rows"] += sum(g * t.n_elems * xb * row
+                                   for t in plan.tiles())
+    got = {(dict(key)["dir"], dict(key)["what"]): v for key, v in
+           metrics.counter("comefa.transfer_bytes").series().items()}
+    assert got == want
 
 
 def test_host_sync_waits_apart_only_when_traced(monkeypatch):

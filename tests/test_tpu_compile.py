@@ -74,3 +74,35 @@ def test_bitplane_matmul_compiles_for_v5e(one_chip):
     compiled = jax.jit(lambda x, w, s: ops.bitplane_matmul(
         x, w, s, bits=bits, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("k,n", [(960, 320), (960, 960), (960, 2560),
+                                 (2560, 960)])
+def test_gemv_placement_compiles_for_v5e(one_chip, k, n):
+    """The batched GEMV's device-side placement at smollm-360m's
+    projection shapes (int4 x int8, 4 slots): the weight and activation
+    plane builders, and one tile's two-range row write into packed state."""
+    from repro.core.comefa import engine_packed, grid, schedule
+    from repro.kernels import comefa_sim
+    from repro.serve.comefa_exec import acc_bits_for
+    g, wb, xb = 4, 4, 8
+    acc = acc_bits_for(wb, xb, k)
+    plan = schedule.cached_plan_gemv(
+        k, n, wb, xb, acc,
+        k_tile=min(k, comefa_sim.gemv_batched_k_tile(wb, xb, acc)))
+    pack = engine_packed.get_engine("packed-xla").pack_rows
+    w_planes = jax.eval_shape(comefa_sim._weight_planer(pack, plan),
+                              jax.ShapeDtypeStruct((k, n), jnp.uint8))
+    x_planes = jax.eval_shape(comefa_sim._x_planer(pack, plan),
+                              jax.ShapeDtypeStruct((g, k), jnp.uint8))
+    assert len(w_planes) == len(x_planes) == plan.n_tiles
+    comefa_sim._weight_planer(pack, plan).lower(
+        _spec((k, n), jnp.uint8, one_chip)).compile()
+    comefa_sim._x_planer(pack, plan).lower(
+        _spec((g, k), jnp.uint8, one_chip)).compile()
+    x_base = comefa_sim._gemv_batched_layout(plan)[0].base
+    planes = tuple(_spec(p.shape, p.dtype, one_chip)
+                   for p in (w_planes[0], x_planes[0]))
+    grid._write_row_ranges.lower(
+        _spec((g, plan.n_blocks, isa.N_ROWS, N_WORDS), jnp.uint32, one_chip),
+        planes, bases=(comefa_sim._weight_bases(plan)[0], x_base)).compile()
