@@ -1,9 +1,12 @@
 """Benchmarks reproducing each table/figure of the CoMeFa paper.
 
-Each function returns a list of (name, value, paper_value_or_None) rows;
-`benchmarks.run` prints them as CSV.  These drive the analytical FPGA
-model whose cycle formulas are validated bit-exactly by the simulator
-tests (tests/test_comefa_sim.py).
+Each function returns a list of (name, value, paper_value_or_None) rows.
+These drive the analytical FPGA model whose cycle formulas are validated
+bit-exactly by the simulator tests (tests/test_comefa_sim.py).  Run as a
+script, the module prints every row as ``name,us_per_call,derived,paper``
+CSV:
+
+  PYTHONPATH=src python benchmarks/paper_figs.py
 """
 from __future__ import annotations
 
@@ -105,3 +108,12 @@ def run(out_rows: list) -> None:
         us = (time.perf_counter() - t0) * 1e6
         for name, value, paper_val in rows:
             out_rows.append((name, us / max(len(rows), 1), value, paper_val))
+
+
+if __name__ == "__main__":
+    rows: list = []
+    run(rows)
+    print("name,us_per_call,derived,paper")
+    for name, us, derived, paper in rows:
+        p = "" if paper is None else f"{paper:.6g}"
+        print(f"{name},{us:.2f},{derived:.6g},{p}")

@@ -1,7 +1,7 @@
 """Quickstart: CoMeFa in 60 seconds, all three layers of the system.
 
   1. bit-level CoMeFa RAM simulator - run a SIMD multiply in a 20Kb block
-  2. TPU bit-plane kernel - the same bit-serial math on the MXU/VPU
+  2. a packed projection - w4 bit-plane weights through `linear`
   3. a quantized model layer - the technique inside a transformer
 
 Run:  PYTHONPATH=src python examples/quickstart.py
@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.comefa import ComefaArray, layout, program, timing
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.quant import bitplane as bp
 
 
@@ -40,19 +40,22 @@ def demo_simulator():
           f"{cycles / 588e6 * 1e9:.0f} ns at CoMeFa-D's 588 MHz")
 
 
-def demo_kernel():
-    print("=== 2. TPU bit-plane kernel: w4 weights x f32 activations ===")
+def demo_linear():
+    print("=== 2. packed projection: w4 weights x f32 activations ===")
+    from repro import configs
+    from repro.models import common
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(8, 256)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(256, 128)), jnp.float32)
-    y4 = ops.quantized_matmul(x, w, bits=4)
+    packed, scale = bp.quantize_pack(w, 4, axis=0)
+    y4 = common.linear({"packed": packed, "scale": scale}, x,
+                       configs.get("smollm-360m"))
     dense = x @ w
     rel = float(jnp.linalg.norm(y4 - dense) / jnp.linalg.norm(dense))
-    print(f"  4-bit bit-plane GEMM vs dense: rel err {rel:.3f}; "
+    print(f"  4-bit bit-plane projection vs dense: rel err {rel:.3f}; "
           f"weight bytes 4x smaller in HBM")
-    packed, scale = bp.quantize_pack(w, 4, axis=0)
     y_ref = ref.bitplane_matmul_ref(x, packed, scale, bits=4)
-    print(f"  kernel == jnp oracle: "
+    print(f"  packed linear == jnp oracle: "
           f"{bool(jnp.allclose(y4, y_ref, atol=1e-4))}")
 
 
@@ -75,5 +78,5 @@ def demo_model():
 
 if __name__ == "__main__":
     demo_simulator()
-    demo_kernel()
+    demo_linear()
     demo_model()

@@ -181,7 +181,7 @@ def gemv_grid(variant: str, g: int = 8, h: int = 512, t: int = 50,
     FSMs instead of looping one FSM over the slices; it approaches g as
     the RAM side dominates.  (The *simulator's* grid-vs-loop wall-clock
     win - one fused grid scan dispatch vs a Python loop of `ComefaArray.run`
-    calls - is measured separately in `benchmarks/sim_speed.py`.)
+    calls - is a property of the simulator, not of this model.)
     """
     assert g >= 1
     macs = g * 4 * h * (2 * h) * t

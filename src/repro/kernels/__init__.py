@@ -1,6 +1,6 @@
-"""Pallas TPU kernels: bit-plane/bit-serial compute (CoMeFa on the MXU/VPU),
-the simulator-backed validation kernels (`comefa_sim`), and the bit-packed
-simulator step kernel itself (`comefa_step`)."""
-from . import comefa_sim, comefa_step, ops, ref
+"""The CoMeFa kernels: the simulator-backed compute kernels (`comefa_sim`),
+the bit-packed simulator step kernel they run on (`comefa_step`), and the
+pure-numpy/jnp references the tests compare them with (`ref`)."""
+from . import comefa_sim, comefa_step, ref
 
-__all__ = ["comefa_sim", "comefa_step", "ops", "ref"]
+__all__ = ["comefa_sim", "comefa_step", "ref"]

@@ -1,5 +1,4 @@
-"""Launchers: mesh builders, dry-run, roofline, train/serve CLIs.
-(dryrun/roofline set XLA device-count flags at import - import lazily.)"""
+"""Launchers: the device mesh (`mesh`) and the train/serve CLIs."""
 from . import mesh
 
 __all__ = ["mesh"]

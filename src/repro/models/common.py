@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from ..quant import bitplane
-from ..kernels import ops as kops
 
 Params = Dict[str, Any]
 
@@ -68,7 +67,6 @@ class Config:
     lru_width: int = 0                     # 0 -> d_model
     # CoMeFa bit-plane quantization (weight-only)
     quant_bits: Optional[int] = None
-    quant_mode: str = "xla"                # xla | pallas
     # numerics / misc
     dtype: str = "bfloat16"
     norm_eps: float = 1e-6
@@ -135,7 +133,7 @@ def _dense_specs(in_axis: Optional[str], out_axis: Optional[str],
 # layer installs an executor here to route eager decode-step GEMVs onto the
 # CoMeFa grid; traced (jitted) calls never see it - the hook only fires on
 # concrete values.  Signature: hook(params, x2 [rows, K], bits) -> [rows, N]
-# array, or None to fall through to the XLA/Pallas path.
+# array, or None to fall through to the XLA path.
 _LINEAR_HOOK = None
 
 
@@ -163,15 +161,11 @@ def linear(params: Params, x: jax.Array, cfg: Config) -> jax.Array:
         y = _LINEAR_HOOK(params, x2, bits)
         if y is not None:
             return y.reshape(*lead, -1).astype(x.dtype)
-    if cfg.quant_mode == "pallas" and jax.default_backend() == "tpu":
-        y = kops.bitplane_matmul(x2.astype(jnp.float32), packed, scale,
-                                 bits=bits)
-    else:
-        # XLA-expressible bit-plane contraction: unpack planes with shifts
-        # (fused by XLA into the dot prologue) - weights cost w bits in HBM.
-        q = bitplane.unpack(packed, bits, axis=0)          # [K, N] int32
-        w = q.astype(x.dtype) * scale.astype(x.dtype)
-        y = x2 @ w
+    # XLA-expressible bit-plane contraction: unpack planes with shifts
+    # (fused by XLA into the dot prologue) - weights cost w bits in HBM.
+    q = bitplane.unpack(packed, bits, axis=0)              # [K, N] int32
+    w = q.astype(x.dtype) * scale.astype(x.dtype)
+    y = x2 @ w
     return y.reshape(*lead, -1).astype(x.dtype)
 
 

@@ -14,10 +14,10 @@ of consecutive tiles visibly overlap on the model track (the paper's
 Sec. IV-A LCU pipeline) while the wall-clock track shows what the
 simulator paid to execute them.
 
-`metrics_summary` flattens the metrics registry into the block embedded
-in ``benchmarks/sim_speed.py --json`` (cache hit rates, host/device
+`metrics_summary` flattens the metrics registry into the block that
+``python -m repro.obs --metrics`` writes (cache hit rates, host/device
 crossings, per-engine dispatch counts) so the nightly artifact tracks
-cache efficacy over time, not just wall-clock.
+cache efficacy over time.
 """
 from __future__ import annotations
 

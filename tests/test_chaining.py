@@ -15,7 +15,7 @@ import pytest
 from repro.core.comefa import (ComefaArray, N_COLS, layout, plan_chain,
                                program, timing)
 from repro.core.comefa.ir import RowAllocator
-from repro.kernels import comefa_sim
+from repro.kernels import comefa_sim, ref
 
 RNG = np.random.default_rng(42)
 
@@ -105,7 +105,8 @@ def test_full_reduce_steps_split():
     assert program.full_reduce_steps(4) == (8, 2)
 
 
-@pytest.mark.parametrize("n_blocks,bits", [(1, 4), (2, 3), (4, 2)])
+@pytest.mark.parametrize("n_blocks,bits", [(1, 4), (2, 3), (4, 2), (4, 4),
+                                           (1, 8), (2, 16)])
 def test_reduce_to_scalar_bit_exact_and_cycles(n_blocks, bits):
     steps, chain_steps = program.full_reduce_steps(n_blocks)
     total_steps = steps + chain_steps
@@ -120,7 +121,7 @@ def test_reduce_to_scalar_bit_exact_and_cycles(n_blocks, bits):
                                            n_blocks=n_blocks))
     assert cyc == timing.chained_reduction_cycles(bits, n_blocks=n_blocks)
     got = int(layout.extract(arr, 0, bits + total_steps, block=0)[0])
-    assert got == int(vals.sum())
+    assert got == ref.bitserial_reduce_ref(vals)
 
 
 def test_chained_groups_straddle_block_seams():

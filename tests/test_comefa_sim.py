@@ -5,6 +5,7 @@ import pytest
 
 from repro.core.comefa import (ComefaArray, N_COLS, isa, layout, program,
                                timing)
+from repro.kernels import ref
 
 RNG = np.random.default_rng(0)
 
@@ -15,6 +16,21 @@ def fresh(n_blocks=1, chain=False):
 
 def rand_u(bits, n=N_COLS, rng=RNG):
     return rng.integers(0, 1 << bits, size=n, dtype=np.int64)
+
+
+def word_rows(words):
+    """uint32 words [rows, N_COLS // 32] -> bit rows [rows, N_COLS]: lane j
+    of a row holds bit j % 32 of word j // 32."""
+    shifts = np.arange(32, dtype=np.uint32)
+    bits = (np.asarray(words, np.uint32)[..., None] >> shifts) & 1
+    return bits.reshape(len(words), -1).astype(np.uint8)
+
+
+def row_words(rows):
+    """Inverse of `word_rows`: bit rows [rows, 32 * w] -> uint32 [rows, w]."""
+    shifts = np.arange(32, dtype=np.uint32)
+    bits = np.asarray(rows, np.uint32).reshape(len(rows), -1, 32)
+    return (bits << shifts).sum(axis=-1, dtype=np.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +123,7 @@ def test_mul_is_simd_across_blocks():
 # logic ops, predication, OOOR
 # ---------------------------------------------------------------------------
 
-def test_bulk_bitwise_ops():
+def test_bitwise_logic_ops():
     arr = fresh()
     n = 8
     a, b = rand_u(n), rand_u(n)
@@ -223,9 +239,9 @@ def test_reduce_tree(steps):
 # database search / RAID (Sec. IV-C)
 # ---------------------------------------------------------------------------
 
-def test_search_replace():
+@pytest.mark.parametrize("n", [16, 8, 12, 20])
+def test_search_replace(n):
     arr = fresh()
-    n = 16
     recs = rand_u(n)
     key = int(recs[7])                                  # ensure >=1 match
     layout.place(arr, recs, 0, n)
@@ -234,8 +250,9 @@ def test_search_replace():
     cyc = arr.run(prog)
     assert cyc == timing.search_cycles(n)
     got = layout.extract(arr, 0, n, block=0)
-    expect = np.where(recs == key, 0, recs)
-    np.testing.assert_array_equal(got, expect)
+    np.testing.assert_array_equal(got, ref.search_replace_ref(recs, key))
+    # the mask latch is left holding the match flag of every lane
+    np.testing.assert_array_equal(arr.mask[0], recs == key)
 
 
 def test_raid_rebuild():
@@ -254,6 +271,37 @@ def test_raid_rebuild():
     prog = program.raid_rebuild(surviving, [10], [20])
     arr.run(prog)
     np.testing.assert_array_equal(arr.mem[0, 20, :], lost)
+
+
+def _raid(stripes, rows_per_stripe):
+    """Stripes of uint32 words [s, rows * 5], one 160-bit row per 5 words:
+    stripe 0 goes in as the parity operand, the rest as data; returns the
+    rebuilt stripe's words."""
+    arr = fresh()
+    rows = []
+    for s, words in enumerate(stripes):
+        base = s * rows_per_stripe
+        arr.mem[0, base:base + rows_per_stripe, :] = word_rows(
+            words.reshape(rows_per_stripe, -1))
+        rows.append(list(range(base, base + rows_per_stripe)))
+    out = list(range(100, 100 + rows_per_stripe))
+    arr.run(program.raid_rebuild(rows[1:], rows[0], out))
+    return row_words(arr.mem[0, 100:100 + rows_per_stripe, :]).reshape(-1)
+
+
+def test_raid_rebuild_matches_xor_ref():
+    stripes = RNG.integers(0, 2**32, size=(5, 4 * N_COLS // 32),
+                           dtype=np.uint64).astype(np.uint32)
+    np.testing.assert_array_equal(_raid(stripes, 4),
+                                  ref.raid_xor_ref(stripes))
+
+
+def test_raid_rebuild_recovers_lost_stripe():
+    data = RNG.integers(0, 2**32, size=(4, 3 * N_COLS // 32),
+                        dtype=np.uint64).astype(np.uint32)
+    parity = ref.raid_xor_ref(data)
+    survivors = np.stack([parity, data[0], data[1], data[3]])
+    np.testing.assert_array_equal(_raid(survivors, 3), data[2])
 
 
 # ---------------------------------------------------------------------------
@@ -428,6 +476,30 @@ def test_read_word_rejects_bad_addresses_like_write_word():
         with pytest.raises(AssertionError):
             arr.write_word(0, bad, 1)
     assert arr.io_words == 0                   # nothing counted on failure
+
+
+@pytest.mark.parametrize("bits", [4, 8, 16])
+def test_place_matches_bit_transpose_ref(bits):
+    """Transposed placement is the bit transpose: bit i of element j lands
+    in row base + i, lane j (blocks side by side)."""
+    arr = fresh(n_blocks=2)
+    x = RNG.integers(0, 1 << bits, size=(2, N_COLS))
+    layout.place(arr, x, 3, bits)
+    planes = arr.mem[:, 3:3 + bits, :].transpose(1, 0, 2).reshape(bits, -1)
+    np.testing.assert_array_equal(row_words(planes),
+                                  ref.bit_transpose_ref(x.reshape(-1), bits))
+    np.testing.assert_array_equal(layout.extract(arr, 3, bits), x)
+
+
+def test_place_extract_roundtrip_signed():
+    bits = 6
+    x = RNG.integers(-(1 << (bits - 1)), 1 << (bits - 1), size=N_COLS)
+    arr = fresh()
+    layout.place(arr, x, 0, bits)
+    np.testing.assert_array_equal(row_words(arr.mem[0, :bits, :]),
+                                  ref.bit_transpose_ref(x, bits))
+    np.testing.assert_array_equal(
+        layout.extract(arr, 0, bits, block=0, signed=True), x)
 
 
 def test_memory_mode_preserved_after_compute():

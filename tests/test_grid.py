@@ -336,6 +336,21 @@ def test_comefa_gemv_batched_matches_numpy_per_slot(g, k, n, seed):
                                       @ x[i].astype(np.int64))
 
 
+@pytest.mark.parametrize("x_bits,w_bits", [(4, 4), (8, 4), (2, 8)])
+def test_comefa_gemv_batched_bit_widths(x_bits, w_bits):
+    """Per-slot weights at several activation/weight widths, exact against
+    the int64 product."""
+    from repro.kernels import comefa_sim
+    rng = np.random.default_rng(17)
+    g, k, n = 2, 32, 128
+    w = rng.integers(0, 1 << w_bits, size=(g, k, n))
+    x = rng.integers(0, 1 << x_bits, size=(g, k))
+    got = comefa_sim.comefa_gemv_batched(w, x, w_bits=w_bits, x_bits=x_bits,
+                                         acc_bits=w_bits + x_bits + 5)
+    np.testing.assert_array_equal(
+        got, np.einsum("gkn,gk->gn", w.astype(np.int64), x.astype(np.int64)))
+
+
 def test_comefa_gemv_batched_agrees_with_single_instance_kernel():
     """The grid sweep and G separate OOOR `comefa_gemv` calls disagree in
     *cycles* (the shared-FSM variant cannot zero-skip) but must agree

@@ -1,5 +1,7 @@
-"""Pallas kernel tests: allclose vs pure-jnp oracles across shape/dtype
-sweeps + hypothesis property tests (interpret mode on CPU)."""
+"""Bit-plane packing (`quant.bitplane`) and the packed projection that
+consumes it (`models.common.linear`'s XLA unpack-and-dot): round trips,
+quantization bounds, and allclose vs the pure-jnp oracle in
+`kernels.ref` across shape/dtype sweeps, plus hypothesis properties."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +14,9 @@ except ImportError:
     # instead of perpetually skipping (see tests/_minihyp.py)
     from _minihyp import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro import configs
+from repro.kernels import ref
+from repro.models import common as cm
 from repro.quant import bitplane as bp
 
 RNG = np.random.default_rng(42)
@@ -76,209 +80,73 @@ def test_quantize_bounds_and_scale():
 
 
 # ---------------------------------------------------------------------------
-# bitplane_matmul (MXU path)
+# packed projection: `linear`'s XLA unpack-and-dot (no linear hook)
 # ---------------------------------------------------------------------------
+
+CFG = configs.get("smollm-360m")
+
+
+def packed_linear(x, packed, scale):
+    return cm.linear({"packed": packed, "scale": scale}, x, CFG)
+
 
 @pytest.mark.parametrize("bits", [2, 4, 8])
 @pytest.mark.parametrize("m,k,n", [(8, 128, 128), (16, 256, 128),
                                    (128, 128, 256), (1, 384, 128)])
-def test_bitplane_matmul_vs_ref(bits, m, k, n):
+def test_packed_linear_vs_ref(bits, m, k, n):
     x = jnp.asarray(RNG.normal(size=(m, k)), jnp.float32)
     w = jnp.asarray(RNG.normal(size=(k, n)), jnp.float32)
     packed, scale = bp.quantize_pack(w, bits, axis=0)
-    y = ops.bitplane_matmul(x, packed, scale, bits=bits)
+    y = packed_linear(x, packed, scale)
     y_ref = ref.bitplane_matmul_ref(x, packed, scale, bits=bits)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_bitplane_matmul_dtypes(dtype):
+def test_packed_linear_dtypes(dtype):
     x = jnp.asarray(RNG.normal(size=(16, 128)), dtype)
     w = jnp.asarray(RNG.normal(size=(128, 128)), jnp.float32)
     packed, scale = bp.quantize_pack(w, 4, axis=0)
-    y = ops.bitplane_matmul(x, packed, scale, bits=4)
-    y_ref = ref.bitplane_matmul_ref(x.astype(jnp.float32), packed, scale,
-                                    bits=4)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               rtol=2e-2, atol=2e-2)
+    y = packed_linear(x, packed, scale)
+    assert y.dtype == dtype
+    y_ref = np.asarray(ref.bitplane_matmul_ref(x.astype(jnp.float32),
+                                               packed, scale, bits=4))
+    # the projection dequantizes the weights in x's dtype and returns
+    # that dtype: each weight and each output is rounded once to it
+    w_deq = np.asarray(bp.unpack(packed, 4, axis=0) * scale)
+    mag = np.abs(np.asarray(x, np.float32)) @ np.abs(w_deq)
+    bound = float(jnp.finfo(dtype).eps) * (mag + np.abs(y_ref))
+    err = np.abs(np.asarray(y, np.float32) - y_ref)
+    assert (err <= bound).all(), float((err / bound).max())
 
 
-def test_bitplane_matmul_block_sweep():
-    """Result must be block-shape invariant."""
-    x = jnp.asarray(RNG.normal(size=(32, 512)), jnp.float32)
-    w = jnp.asarray(RNG.normal(size=(512, 256)), jnp.float32)
-    packed, scale = bp.quantize_pack(w, 4, axis=0)
-    y0 = ops.bitplane_matmul(x, packed, scale, bits=4,
-                             block_m=32, block_n=128, block_k=128)
-    for bm, bn, bk in [(8, 128, 512), (16, 256, 256), (32, 128, 64)]:
-        y = ops.bitplane_matmul(x, packed, scale, bits=4,
-                                block_m=bm, block_n=bn, block_k=bk)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y0),
-                                   rtol=1e-5, atol=1e-4)
-
-
-def test_quantized_matmul_approximates_dense():
+def test_packed_linear_approximates_dense():
     x = jnp.asarray(RNG.normal(size=(16, 256)), jnp.float32)
     w = jnp.asarray(RNG.normal(size=(256, 128)), jnp.float32)
-    y8 = ops.quantized_matmul(x, w, bits=8)
     dense = x @ w
-    rel = float(jnp.linalg.norm(y8 - dense) / jnp.linalg.norm(dense))
+
+    def rel_err(bits):
+        y = packed_linear(x, *bp.quantize_pack(w, bits, axis=0))
+        return float(jnp.linalg.norm(y - dense) / jnp.linalg.norm(dense))
+
+    rel, rel2 = rel_err(8), rel_err(2)
     assert rel < 0.01                       # 8-bit: <1% relative error
-    y2 = ops.quantized_matmul(x, w, bits=2)
-    rel2 = float(jnp.linalg.norm(y2 - dense) / jnp.linalg.norm(dense))
     assert rel < rel2 < 1.0                 # precision-agnostic degradation
 
 
 @given(bits=st.sampled_from([2, 4, 8]), seed=st.integers(0, 2**31 - 1))
 @settings(max_examples=10, deadline=None)
-def test_bitplane_matmul_exact_on_integers(bits, seed):
-    """With integer x and scale 1, the kernel must be *exact*."""
+def test_packed_linear_exact_on_integers(bits, seed):
+    """With integer x and scale 1, the projection must be *exact*."""
     rng = np.random.default_rng(seed)
     lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
     q = jnp.asarray(rng.integers(lo, hi + 1, size=(128, 128)), jnp.int32)
     x = jnp.asarray(rng.integers(-8, 8, size=(8, 128)), jnp.float32)
     packed = bp.pack(q, bits, axis=0)
     scale = jnp.ones((1, 128), jnp.float32)
-    y = ops.bitplane_matmul(x, packed, scale, bits=bits)
-    expect = x @ q.astype(jnp.float32)
+    y = packed_linear(x, packed, scale)
+    expect = ref.bitplane_matmul_ref(x, packed, scale, bits=bits)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(expect))
-
-
-# ---------------------------------------------------------------------------
-# bitserial_matmul (popcount path)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("a_bits,w_bits", [(4, 4), (8, 4), (2, 8)])
-def test_bitserial_matmul_vs_ref(a_bits, w_bits):
-    m, k, n = 8, 512, 128
-    x = jnp.asarray(RNG.normal(size=(m, k)), jnp.float32)
-    w = jnp.asarray(RNG.normal(size=(k, n)), jnp.float32)
-    qx, sx = bp.quantize(x, a_bits, axis=1)          # per-row
-    qw, sw = bp.quantize(w, w_bits, axis=0)          # per-col
-    xp = jnp.moveaxis(bp.pack(qx, a_bits, axis=1), 0, 1)   # [M, a, K/32]
-    wp = bp.pack(qw, w_bits, axis=0)
-    y = ops.bitserial_matmul(xp, wp, sx, sw, a_bits=a_bits, w_bits=w_bits)
-    y_ref = ref.bitserial_matmul_ref(xp, wp, sx, sw, a_bits=a_bits,
-                                     w_bits=w_bits)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
-                               rtol=1e-5, atol=1e-4)
-    # and against the true dense product, within quantization error
-    # (2-bit symmetric quantization of a Gaussian is inherently coarse)
-    dense = x @ w
-    rel = float(jnp.linalg.norm(y - dense) / jnp.linalg.norm(dense))
-    assert rel < (0.25 if min(a_bits, w_bits) >= 4 else 0.95)
-
-
-def test_bitserial_matches_bitplane_path():
-    """Same weights, integer activations: both kernels agree exactly."""
-    m, k, n, bits = 8, 256, 128, 4
-    rng = np.random.default_rng(3)
-    qx = jnp.asarray(rng.integers(-8, 8, size=(m, k)), jnp.int32)
-    qw = jnp.asarray(rng.integers(-8, 8, size=(k, n)), jnp.int32)
-    ones_m = jnp.ones((m, 1), jnp.float32)
-    ones_n = jnp.ones((1, n), jnp.float32)
-    wp = bp.pack(qw, bits, axis=0)
-    y1 = ops.bitplane_matmul(qx.astype(jnp.float32), wp, ones_n, bits=bits)
-    xp = jnp.moveaxis(bp.pack(qx, 5, axis=1), 0, 1)
-    y2 = ops.bitserial_matmul(xp, wp, ones_m, ones_n, a_bits=5, w_bits=bits)
-    np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
-
-
-# ---------------------------------------------------------------------------
-# bulk bitwise: search / RAID
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("bits,n", [(16, 2048), (20, 4096), (8, 32 * 17)])
-def test_search_replace_vs_ref(bits, n):
-    recs = RNG.integers(0, 1 << bits, size=n)
-    key = int(recs[5])
-    packed = jnp.asarray(ref.bit_transpose_ref(recs, bits))
-    w = packed.shape[1]
-    bw = w if w < 512 else 512
-    out, mask = ops.search_replace(packed, bits=bits, key=key, block_w=bw)
-    got = np.asarray(bp.unpack(out, bits, axis=0)) & ((1 << bits) - 1)
-    np.testing.assert_array_equal(got, ref.search_replace_ref(recs, key))
-    # mask bit n%32 of word n//32 set iff record n matched
-    m = np.asarray(mask)
-    match_bits = (m[np.arange(n) // 32] >> (np.arange(n) % 32)) & 1
-    np.testing.assert_array_equal(match_bits, (recs == key).astype(np.uint32))
-
-
-def test_raid_xor_vs_ref():
-    stripes = RNG.integers(0, 2**32, size=(5, 4096), dtype=np.uint64
-                           ).astype(np.uint32)
-    got = ops.raid_xor(jnp.asarray(stripes))
-    np.testing.assert_array_equal(np.asarray(got), ref.raid_xor_ref(stripes))
-
-
-def test_raid_rebuild_recovers_lost_stripe():
-    data = RNG.integers(0, 2**31, size=(4, 1024)).astype(np.uint32)
-    parity = np.bitwise_xor.reduce(data, axis=0)
-    lost = data[2]
-    survivors = np.stack([data[0], data[1], data[3], parity])
-    got = ops.raid_xor(jnp.asarray(survivors))
-    np.testing.assert_array_equal(np.asarray(got), lost)
-
-
-# ---------------------------------------------------------------------------
-# bitserial_reduce
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("bits,n", [(4, 2048), (8, 4096), (16, 1024)])
-def test_bitserial_reduce_vs_ref(bits, n):
-    lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
-    vals = RNG.integers(lo, hi + 1, size=n)
-    packed = bp.pack(jnp.asarray(vals, jnp.int32), bits, axis=0)
-    got = ops.bitserial_reduce(packed, bits=bits,
-                               block_w=min(512, n // 32))
-    assert float(got) == ref.bitserial_reduce_ref(vals)
-
-
-@given(bits=st.sampled_from([4, 8, 12]), seed=st.integers(0, 2**31 - 1))
-@settings(max_examples=15, deadline=None)
-def test_bitserial_reduce_property(bits, seed):
-    rng = np.random.default_rng(seed)
-    lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
-    vals = rng.integers(lo, hi + 1, size=1024)
-    packed = bp.pack(jnp.asarray(vals, jnp.int32), bits, axis=0)
-    got = ops.bitserial_reduce(packed, bits=bits, block_w=32)
-    assert float(got) == float(vals.astype(np.int64).sum())
-
-
-# ---------------------------------------------------------------------------
-# bit_transpose (swizzle)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("bits", [4, 8, 16])
-def test_bit_transpose_vs_ref(bits):
-    n = 32 * 256 * 2
-    x = RNG.integers(0, 1 << bits, size=n)
-    got = ops.bit_transpose(jnp.asarray(x, jnp.int32), bits=bits)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  ref.bit_transpose_ref(x, bits))
-
-
-def test_bit_transpose_roundtrip_signed():
-    bits, n = 6, 32 * 256
-    lo, hi = -(1 << (bits - 1)), (1 << (bits - 1)) - 1
-    x = RNG.integers(lo, hi + 1, size=n)
-    packed = ops.bit_transpose(jnp.asarray(x, jnp.int32), bits=bits)
-    back = ops.bit_untranspose(packed, bits=bits, signed=True)
-    np.testing.assert_array_equal(np.asarray(back), x)
-
-
-def test_swizzle_kernel_agrees_with_simulator_layout():
-    """The TPU swizzle and the CoMeFa swizzle are the same bit permutation
-    modulo word width (32 vs 40): both store bit i of element j at
-    (plane i, word j//W, position j%W)."""
-    from repro.core.comefa import layout
-    bits, n = 8, 40 * 8
-    x = RNG.integers(0, 1 << bits, size=n)
-    words = np.stack([layout.swizzle(x[c * 40:(c + 1) * 40], bits)
-                      for c in range(n // 40)])     # [chunks, bits]
-    for i in range(bits):
-        for c in range(n // 40):
-            for j in range(40):
-                bit_sim = (int(words[c, i]) >> j) & 1
-                assert bit_sim == (int(x[c * 40 + j]) >> i) & 1
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(x @ q.astype(jnp.float32)))

@@ -22,7 +22,7 @@ def _run(args, timeout=600, env_extra=None):
 def test_quickstart_example():
     out = _run([os.path.join(REPO, "examples", "quickstart.py")])
     assert "paper formula n^2+3n-2 = 86" in out
-    assert "kernel == jnp oracle: True" in out
+    assert "packed linear == jnp oracle: True" in out
     assert "finite: True" in out
 
 

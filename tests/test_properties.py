@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.comefa import ComefaArray, N_COLS, isa, layout, program, \
     timing
+from repro.kernels import ref
 from repro.quant import bitplane as bp
 
 
@@ -95,6 +96,24 @@ def test_chained_reduce_tree_matches_numpy(width, n_blocks, seed):
     assert got == int(vals.sum())
 
 
+@given(width=st.sampled_from([4, 8, 12]), used=st.integers(1, N_COLS),
+       seed=st.integers(0, 2**31 - 1))
+@settings(max_examples=8, deadline=None)
+def test_reduce_to_scalar_matches_ref_on_partial_rows(width, used, seed):
+    """A scalar reduction over the first `used` lanes (the rest zero)
+    equals the reference sum of those values, at wide precisions."""
+    rng = np.random.default_rng(seed)
+    vals = rng.integers(0, 1 << width, size=used)
+    steps, _ = program.full_reduce_steps(1)
+    arr = ComefaArray()
+    layout.place(arr, vals, 0, width)
+    val = list(range(width + steps))
+    scratch = list(range(width + steps, 2 * (width + steps) - 1))
+    arr.run(program.reduce_to_scalar(val, scratch, width))
+    got = int(layout.extract(arr, 0, width + steps, block=0)[0])
+    assert got == ref.bitserial_reduce_ref(vals)
+
+
 @given(n=st.integers(2, 10))
 @settings(max_examples=9, deadline=None)
 def test_cycle_formulas_monotone(n):
@@ -143,16 +162,18 @@ def test_dequantize_error_bound(bits, seed):
 
 @given(seed=st.integers(0, 2**31 - 1))
 @settings(max_examples=10, deadline=None)
-def test_bitplane_matmul_linearity(seed):
-    """Kernel output is linear in x: f(a*x1 + x2) = a*f(x1) + f(x2)."""
-    from repro.kernels import ops
+def test_packed_linear_linearity(seed):
+    """The packed projection is linear in x: f(a*x1 + x2) = a*f(x1) + f(x2)."""
+    from repro import configs
+    from repro.models import common
     rng = np.random.default_rng(seed)
     w = jnp.asarray(rng.normal(size=(128, 128)), jnp.float32)
     packed, scale = bp.quantize_pack(w, 4, axis=0)
     x1 = jnp.asarray(rng.normal(size=(8, 128)), jnp.float32)
     x2 = jnp.asarray(rng.normal(size=(8, 128)), jnp.float32)
     def f(x):
-        return ops.bitplane_matmul(x, packed, scale, bits=4)
+        return common.linear({"packed": packed, "scale": scale}, x,
+                             configs.get("smollm-360m"))
     lhs = f(2.0 * x1 + x2)
     rhs = 2.0 * f(x1) + f(x2)
     np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs),

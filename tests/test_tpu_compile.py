@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.comefa import isa
 from repro.core.comefa.engine_packed import N_WORDS
-from repro.kernels import comefa_step, ops
+from repro.kernels import comefa_step
 
 G = 8
 
@@ -64,16 +64,20 @@ def test_comefa_step_compiles_for_v5e(one_chip, nb, per_slot, chain):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_bitplane_matmul_compiles_for_v5e(one_chip):
-    """The MXU bit-plane kernel at a smollm-360m FFN projection, w4:
-    K=960 is not a multiple of the K block, so it runs as one block."""
+def test_packed_linear_compiles_for_v5e(one_chip):
+    """`linear`'s packed XLA branch (unpack-and-dot, no linear hook) at a
+    smollm-360m FFN projection, w4: K=960 packs into 30 uint32 words."""
+    from repro import configs
+    from repro.models import common
     k, n, bits = 960, 2560, 4
-    args = [_spec((8, k), jnp.float32, one_chip),
+    cfg = configs.get("smollm-360m")
+    args = [_spec((8, k), jnp.bfloat16, one_chip),
             _spec((bits, k // 32, n), jnp.uint32, one_chip),
             _spec((1, n), jnp.float32, one_chip)]
-    compiled = jax.jit(lambda x, w, s: ops.bitplane_matmul(
-        x, w, s, bits=bits, interpret=False)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    compiled = jax.jit(lambda x, w, s: common.linear(
+        {"packed": w, "scale": s}, x, cfg)).lower(*args).compile()
+    assert compiled.out_info.shape == (8, n)
+    assert compiled.out_info.dtype == jnp.bfloat16
 
 
 @pytest.mark.parametrize("k,n", [(960, 320), (960, 960), (960, 2560),
