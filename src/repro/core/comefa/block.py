@@ -386,11 +386,14 @@ class _EncodeCacheStats(MutableMapping):
 ENCODE_CACHE_STATS = _EncodeCacheStats()
 
 
-def _encode_cached(key, producer) -> np.ndarray:
-    mat = _ENCODE_CACHE.get(key)
-    if mat is not None:
+def _encode_cached(key, producer):
+    """``(canonical key, frozen matrix)`` for `key`: the cache stores the
+    key object of the first lookup, and a hit hands that object back so
+    an `ir.Program` can adopt it (`Program.adopt_key`)."""
+    entry = _ENCODE_CACHE.get(key)
+    if entry is not None:
         _ENCODE_EVENTS.inc(event="hits")
-        return mat
+        return entry
     _ENCODE_EVENTS.inc(event="misses")
     with obs_trace.span("comefa.encode"):
         mat = producer()
@@ -400,8 +403,8 @@ def _encode_cached(key, producer) -> np.ndarray:
     mat.setflags(write=False)
     if len(_ENCODE_CACHE) >= _ENCODE_CACHE_MAX:
         _ENCODE_CACHE.pop(next(iter(_ENCODE_CACHE)))   # FIFO eviction
-    _ENCODE_CACHE[key] = mat
-    return mat
+    _ENCODE_CACHE[key] = (key, mat)
+    return key, mat
 
 
 def _widen_legacy(mat: np.ndarray) -> np.ndarray:
@@ -447,9 +450,11 @@ def encoded(program) -> np.ndarray:
         return program
     if isinstance(program, ir.Program):
         verify.maybe_verify(program)
-        return _encode_cached(program.key, program.encode)
+        key, mat = _encode_cached(program.key, program.encode)
+        program.adopt_key(key)
+        return mat
     instrs = tuple(program)
-    return _encode_cached(instrs, lambda: encode_program(instrs))
+    return _encode_cached(instrs, lambda: encode_program(instrs))[1]
 
 
 # device-side companion to the encode cache: the frozen host matrix used
@@ -462,11 +467,12 @@ _DEVICE_MAT_CACHE_MAX = 512
 def device_mat(mat: np.ndarray, kind: str = "array"):
     """Device-side copy of an encoded program matrix, cached when safe.
 
-    Only *frozen* matrices cache - exactly the encode-cache residents
-    (`_encode_cached` calls ``setflags(write=False)``) and anything else
-    a caller deliberately froze.  A writable matrix may be mutated or
+    Only *frozen* matrices cache - the encode-cache residents
+    (`_encode_cached` calls ``setflags(write=False)``), the per-slot
+    stacks `grid._slot_stack` keeps, and anything else a caller
+    deliberately froze.  A writable matrix may be mutated or
     garbage-collected after this call, so it uploads fresh each time
-    (temporary `_concat_encoded` / `run_per_slot` stacks take this path).
+    (temporary `_concat_encoded` stacks take this path).
     Entries key on ``id(mat)`` and hold a strong reference to the host
     matrix, so an id can never be recycled out from under its entry;
     FIFO eviction bounds both caches the same way.  Every upload counts

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...obs import metrics as obs_metrics
 from ...obs import trace as obs_trace
 from . import block, isa, verify
 from .block import (ComefaArray, encoded, read_port_word, write_port_word)
@@ -94,6 +95,44 @@ def _read_rows(unpack_rows, mem, base: int, n: int, lane_step: int):
 # the number of distinct scan lengths (= jit retraces) stays bounded across
 # a sweep of value-dependent program lengths
 _SLOT_PAD_QUANTUM = 32
+
+# frozen per-slot stacks by the identities of the frozen matrices they pad
+# (plus the padded length): a repeated program list reuses its stack, and
+# `block.device_mat` then finds the stack's device copy.  Each entry holds
+# its matrices, so their ids cannot be recycled while it lives; FIFO
+# eviction also drops the evicted stack's device copy.
+#   comefa.slot_stack{event=build|reuse}
+_SLOT_STACK_CACHE: dict = {}
+_SLOT_STACK_CACHE_MAX = 4
+_SLOT_STACK = obs_metrics.counter("comefa.slot_stack")
+
+
+def _slot_stack(mats: Sequence[np.ndarray], t_pad: int) -> np.ndarray:
+    """`mats` padded with idle cycles to ``[len(mats), t_pad, F]``.
+
+    Frozen matrices (the encode cache's) give a frozen stack, cached and
+    reused for the same list; a writable one may change after this call,
+    so its stack is built fresh and left writable.
+    """
+    frozen = not any(m.flags.writeable for m in mats)
+    if frozen:
+        key = (tuple(map(id, mats)), t_pad)
+        entry = _SLOT_STACK_CACHE.get(key)
+        if entry is not None:
+            _SLOT_STACK.inc(event="reuse")
+            return entry[1]
+    _SLOT_STACK.inc(event="build")
+    stack = np.zeros((len(mats), t_pad, isa.N_ENGINE_FIELDS),
+                     dtype=np.int32)   # zero fields == idle
+    for g, m in enumerate(mats):
+        stack[g, :m.shape[0]] = m
+    if frozen:
+        stack.setflags(write=False)
+        if len(_SLOT_STACK_CACHE) >= _SLOT_STACK_CACHE_MAX:
+            _, old = _SLOT_STACK_CACHE.pop(next(iter(_SLOT_STACK_CACHE)))
+            block._DEVICE_MAT_CACHE.pop(id(old), None)
+        _SLOT_STACK_CACHE[key] = (tuple(mats), stack)
+    return stack
 
 
 class _Slot:
@@ -405,10 +444,7 @@ class ComefaGrid:
                 # lengths a sweep of value-dependent programs can trigger
                 # (each length is one jit trace)
                 t_pad = -(-longest // _SLOT_PAD_QUANTUM) * _SLOT_PAD_QUANTUM
-                stack = np.zeros((self.g, t_pad, isa.N_ENGINE_FIELDS),
-                                 dtype=np.int32)   # zero fields == idle
-                for g, m in enumerate(mats):
-                    stack[g, :m.shape[0]] = m
+                stack = _slot_stack(mats, t_pad)
             engine = self._active_engine()
             # makespan = the longest real program: slices run concurrently,
             # the slowest bounds the dispatch

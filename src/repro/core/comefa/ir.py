@@ -401,6 +401,33 @@ def recode_digits(x: int, n_bits: int, recode: str = "naive") -> List[int]:
 # the Program IR container
 # ---------------------------------------------------------------------------
 
+class ProgramKey:
+    """Structural fingerprint of a program's slots, hashed once.
+
+    A plain tuple of slot tuples re-hashes every instruction on every
+    dict lookup; this key hashes them when it is built and compares
+    structurally (an equal hash first, then the slots), so equal
+    programs still share one encode-cache entry.  A lookup with the very
+    key object the cache stores is an identity hit and compares nothing.
+    """
+
+    __slots__ = ("slots", "_hash")
+
+    def __init__(self, slots: Iterable[Slot]):
+        self.slots = tuple(slots)
+        self._hash = hash(self.slots)
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def __eq__(self, other) -> bool:
+        if self is other:
+            return True
+        if not isinstance(other, ProgramKey):
+            return NotImplemented
+        return self._hash == other._hash and self.slots == other.slots
+
+
 class Program:
     """An instruction stream as a first-class, optimisable object.
 
@@ -545,11 +572,16 @@ class Program:
 
     # -- encode cache ------------------------------------------------------
     @property
-    def key(self) -> Tuple:
+    def key(self) -> ProgramKey:
         """Structural fingerprint: keys the simulator's encode cache."""
         if self._key is None:
-            self._key = tuple(self._slots)
+            self._key = ProgramKey(self._slots)
         return self._key
+
+    def adopt_key(self, key: ProgramKey) -> None:
+        """Take `key`, a structurally equal key object (the encode cache's
+        own), so this program's next lookup there is an identity hit."""
+        self._key = key
 
     def encode(self) -> np.ndarray:
         """Engine field matrix [cycles, N_ENGINE_FIELDS] (cached)."""

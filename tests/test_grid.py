@@ -19,9 +19,11 @@ except ImportError:
     # fall back to the deterministic seeded sampler (tests/_minihyp.py)
     from _minihyp import given, settings, strategies as st
 
-from repro.core.comefa import (ComefaArray, ComefaGrid, N_COLS, grid_mesh,
-                               ir, isa, layout, program)
+from repro.core.comefa import (ComefaArray, ComefaGrid, N_COLS, block,
+                               grid_mesh, ir, isa, layout, program)
+from repro.core.comefa import grid as grid_mod
 from repro.core.comefa.grid import grid_shardings
+from repro.obs import metrics
 from repro.core.comefa.isa import PRED_CARRY, ROW_ONES, ROW_ZEROS
 
 SEEDS = st.integers(0, 2**31 - 1)
@@ -275,6 +277,97 @@ def test_grid_accepts_legacy_encoded_matrix_and_empty_programs():
     assert grid.run(ir.Program()) == 0
     assert grid.run_programs([]) == []
     assert grid.cycles == before
+
+
+# ---------------------------------------------------------------------------
+# per-slot stack reuse
+# ---------------------------------------------------------------------------
+
+def _mac_progs(widths):
+    """One ``p <- a * b; acc <- acc + p`` program per n-bit width."""
+    return [(program.mul(list(range(n)), list(range(n, 2 * n)),
+                         list(range(2 * n, 4 * n)))
+             + program.add_into(list(range(4 * n, 6 * n)),
+                                list(range(2 * n, 4 * n)), 0)).optimize()
+            for n in widths]
+
+
+def _slot_stack_events():
+    c = metrics.counter("comefa.slot_stack")
+    return c.value(event="build"), c.value(event="reuse")
+
+
+def test_run_per_slot_reuses_the_frozen_stack_of_a_repeated_list():
+    """The second dispatch of one program list reuses the first one's
+    frozen stack and leaves every slot as fresh stacks and isolated
+    arrays would."""
+    grid_mod._SLOT_STACK_CACHE.clear()
+    widths = (2, 3, 4)
+    progs = _mac_progs(widths)
+    rng = np.random.default_rng(11)
+    grid = ComefaGrid(3, n_blocks=2, engine="packed-xla")
+    ref = ComefaGrid(3, n_blocks=2, engine="reference")
+    arrays = [ComefaArray(n_blocks=2) for _ in widths]
+    for g, n in enumerate(widths):
+        vals = rng.integers(0, 1 << n, size=(2, N_COLS))
+        for tgt in (grid.slot(g), ref.slot(g), arrays[g]):
+            layout.place(tgt, vals, 0, n)
+            layout.place(tgt, vals ^ 1, n, n)
+    build, reuse = _slot_stack_events()
+    counts = grid.run_per_slot(progs)
+    assert _slot_stack_events() == (build + 1, reuse)
+    (mats, stack), = grid_mod._SLOT_STACK_CACHE.values()
+    assert not stack.flags.writeable
+    with pytest.raises(ValueError):
+        stack[0, 0, 0] = 1
+    assert grid.run_per_slot(progs) == counts
+    assert _slot_stack_events() == (build + 1, reuse + 1)
+    for _ in range(2):                 # a fresh stack for every dispatch
+        grid_mod._SLOT_STACK_CACHE.clear()
+        assert ref.run_per_slot(progs) == counts
+    assert counts == [p.cycles for p in progs]
+    for arr, p in zip(arrays, progs):
+        arr.run(p)
+        arr.run(p)
+    assert grid.cycles == ref.cycles == 2 * max(counts)
+    for g, arr in enumerate(arrays):
+        for got in (grid, ref):
+            np.testing.assert_array_equal(got.mem[g], arr.mem)
+            np.testing.assert_array_equal(got.carry[g], arr.carry)
+            np.testing.assert_array_equal(got.mask[g], arr.mask)
+
+
+def test_run_per_slot_builds_a_new_stack_for_a_changed_list():
+    """Another list, another order or one replaced program builds; an
+    equal rebuild of the list reuses; eviction drops the device copy."""
+    grid_mod._SLOT_STACK_CACHE.clear()
+    progs = _mac_progs((2, 3, 4))
+    grid = ComefaGrid(3, engine="reference")
+
+    def builds(lst):
+        before = _slot_stack_events()
+        grid.run_per_slot(lst)
+        after = _slot_stack_events()
+        return after[0] - before[0], after[1] - before[1]
+
+    assert builds(progs) == (1, 0)
+    (_, first), = grid_mod._SLOT_STACK_CACHE.values()
+    assert id(first) in block._DEVICE_MAT_CACHE
+    swapped = list(progs)
+    swapped[1] = _mac_progs((4,))[0]
+    assert builds(swapped) == (1, 0)
+    assert builds(progs[::-1]) == (1, 0)
+    assert builds(_mac_progs((2, 3, 4))) == (0, 1)   # structurally equal
+    # a writable matrix may change after the call: built every time and
+    # never cached
+    raw = [np.array(block.encoded(p)) for p in progs]
+    cached = dict(grid_mod._SLOT_STACK_CACHE)
+    assert builds(raw) == (1, 0) and builds(raw) == (1, 0)
+    assert grid_mod._SLOT_STACK_CACHE == cached
+    for n in range(2, 2 + grid_mod._SLOT_STACK_CACHE_MAX):
+        assert builds(_mac_progs((n, n, n))) == (1, 0)
+    assert len(grid_mod._SLOT_STACK_CACHE) == grid_mod._SLOT_STACK_CACHE_MAX
+    assert id(first) not in block._DEVICE_MAT_CACHE
 
 
 def test_grid_rejects_mismatched_arrays():

@@ -370,6 +370,73 @@ def test_encode_cache_hits_on_structurally_equal_programs():
     assert block.ENCODE_CACHE_STATS["hits"] == 3
 
 
+def _stats_reset():
+    block._ENCODE_CACHE.clear()
+    block.ENCODE_CACHE_STATS.update(hits=0, misses=0,
+                                    device_hits=0, device_misses=0)
+
+
+def _add6():
+    return program.add(list(range(6)), list(range(6, 12)),
+                       list(range(12, 19)))
+
+
+def test_program_key_hashes_once_and_equal_rebuild_adopts_it(monkeypatch):
+    """A repeated lookup re-hashes no instruction, and a structurally
+    equal rebuild hits and takes the cache's own key object."""
+    _stats_reset()
+    hashed = []
+    instr_hash = isa.Instr.__hash__
+
+    def counting_hash(self):
+        hashed.append(self)
+        return instr_hash(self)
+
+    monkeypatch.setattr(isa.Instr, "__hash__", counting_hash)
+    prog = _add6()
+    mat = block.encoded(prog)
+    after_first = len(hashed)
+    assert after_first >= prog.n_instrs        # the key hashed its slots
+    key = prog.key
+    assert block.encoded(prog) is mat
+    assert len(hashed) == after_first          # identity hit: no re-hash
+    assert hash(key) == hash(key) and len(hashed) == after_first
+    rebuilt = _add6()
+    assert rebuilt.key is not key and rebuilt.key == key
+    assert block.encoded(rebuilt) is mat
+    assert rebuilt.key is key                  # adopted the canonical key
+    assert block.ENCODE_CACHE_STATS["hits"] == 2
+    assert block.ENCODE_CACHE_STATS["misses"] == 1
+
+
+def test_mutated_program_rekeys_and_misses():
+    _stats_reset()
+    prog = _add6()
+    mat = block.encoded(prog)
+    key = prog.key
+    prog.append(isa.latch_clear())
+    assert prog.key is not key and prog.key != key
+    grown = block.encoded(prog)
+    assert grown.shape[0] == mat.shape[0] + 1
+    assert block.ENCODE_CACHE_STATS["misses"] == 2
+    assert block.ENCODE_CACHE_STATS["hits"] == 0
+
+
+def test_encode_cache_clear_misses_with_an_adopted_key():
+    """Clearing the cache makes the next lookup a miss, even for a
+    program holding the key object the cleared cache stored."""
+    _stats_reset()
+    prog, rebuilt = _add6(), _add6()
+    block.encoded(prog)
+    block.encoded(rebuilt)
+    assert rebuilt.key is prog.key
+    block._ENCODE_CACHE.clear()
+    block.encoded(rebuilt)
+    assert block.ENCODE_CACHE_STATS["misses"] == 2
+    block.encoded(prog)
+    assert block.ENCODE_CACHE_STATS["hits"] == 2
+
+
 def test_run_programs_single_dispatch_equals_sequential():
     n = 4
     a, b = rand_u(n), rand_u(n)
